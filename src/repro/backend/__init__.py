@@ -1,52 +1,49 @@
-"""Version-adaptive backend: tile-centric primitives -> the installed JAX.
+"""Backend: tile-centric primitives -> JAX 0.9 Pallas/Mosaic.
 
 TileLink's design keeps primitives *tile-centric* and pushes every
-platform/toolchain quirk into a backend that lowers them to whatever the
-target actually supports.  This package is that backend for the JAX/Pallas
-port: the single point where kernels, tile primitives, and the mesh layer
-touch version-sensitive JAX API.  Nothing outside ``repro.backend`` may
-import ``jax.experimental.pallas.tpu`` (enforced by tests/test_backend.py).
+platform quirk into a backend that lowers them to what the target supports.
+This package is that backend for the JAX/Pallas port: the single point where
+kernels, tile primitives, and the mesh layer touch the Pallas TPU API.
+Nothing outside ``repro.backend`` may import ``jax.experimental.pallas.tpu``
+(enforced by tests/test_backend.py).
 
-Supported-JAX policy
---------------------
-Feature-detected at import (``hasattr`` probes, see ``features.py``), not
-version-gated.  Exercised in CI against:
-
-  * jax 0.4.3x  — ``pltpu.TPUCompilerParams``, experimental ``shard_map``
-    (``check_rep``/``auto``), no ``AxisType``, no TPU interpreter class
-    (plain ``interpret=True`` + discharge rules; remote DMAs need scalar
-    LOGICAL device ids, remote semaphore_signal unsupported);
-  * jax >= 0.6/0.7 — ``pltpu.CompilerParams``, public ``jax.shard_map``
-    (``check_vma``/``axis_names``), ``AxisType`` mesh types,
-    ``pltpu.InterpretParams`` TPU interpreter.
-
-Anything in between resolves by probe.  New drift belongs HERE, never in
-kernels.
+Supported JAX
+-------------
+One release: jax/jaxlib 0.9.0 with libtpu 0.0.34 (pinned in
+``requirements-dev.txt``), on the CPU for tests and on TPU v5e.  There are no
+branches for other releases; a new JAX is adopted by moving the pin and
+fixing what breaks.
 
 Targets
 -------
 ``target()`` returns "tpu" (Mosaic lowering, ICI remote DMAs) or "emulated"
-(forced ``interpret`` execution so the full suite and benchmarks run on any
-CPU-only host).  Override with ``REPRO_BACKEND=tpu|emulated|auto``.
+(the TPU interpreter, so the full suite runs on a CPU-only host).  Override
+with ``REPRO_BACKEND=tpu|emulated|auto``.
 
 Surface
 -------
-  mesh / manual regions:   make_mesh, shard_map
+  mesh / manual regions:   make_mesh, shard_map, axis_size
   kernel launch:           pallas_call, compiler_params, prefetch_grid_spec,
-                           pl (stable pallas frontend handle), ANY
+                           pl (pallas frontend handle), HBM, SMEM
   allocation:              vmem_scratch, smem_scratch, dma_semaphore,
-                           regular_semaphore
+                           regular_semaphore, barrier_semaphore
   tile data movement:      make_async_copy, make_async_remote_copy (by
                            logical rank), semaphore_signal, semaphore_wait
   target control:          target, is_emulated, resolve_interpret,
                            default_interpret, describe
-  compute-hardware probes: mxu_dim, vmem_budget_bytes, sublane_multiple,
-                           lane_multiple (tile-lattice pruning, repro.tune)
+  hardware table:          chip, device_kind, mxu_dim, vmem_budget_bytes,
+                           vmem_array_bytes, vmem_limit_bytes,
+                           sublane_multiple, lane_multiple
+  compile cache:           enable_compile_cache
 """
 from repro.backend.features import describe
 from repro.backend.hw import (
+    chip,
+    device_kind,
     mxu_dim,
     vmem_budget_bytes,
+    vmem_array_bytes,
+    vmem_limit_bytes,
     sublane_multiple,
     lane_multiple,
 )
@@ -59,7 +56,8 @@ from repro.backend.target import (
 from repro.backend.mesh import make_mesh, shard_map, axis_size
 from repro.backend.lowering import (
     pl,
-    ANY,
+    HBM,
+    SMEM,
     compiler_params,
     pallas_call,
     prefetch_grid_spec,
@@ -67,16 +65,22 @@ from repro.backend.lowering import (
     smem_scratch,
     dma_semaphore,
     regular_semaphore,
+    barrier_semaphore,
     make_async_copy,
     make_async_remote_copy,
     semaphore_signal,
     semaphore_wait,
 )
+from repro.backend.compile_cache import enable_compile_cache
 
 __all__ = [
     "describe",
+    "chip",
+    "device_kind",
     "mxu_dim",
     "vmem_budget_bytes",
+    "vmem_array_bytes",
+    "vmem_limit_bytes",
     "sublane_multiple",
     "lane_multiple",
     "target",
@@ -87,7 +91,8 @@ __all__ = [
     "shard_map",
     "axis_size",
     "pl",
-    "ANY",
+    "HBM",
+    "SMEM",
     "compiler_params",
     "pallas_call",
     "prefetch_grid_spec",
@@ -95,8 +100,10 @@ __all__ = [
     "smem_scratch",
     "dma_semaphore",
     "regular_semaphore",
+    "barrier_semaphore",
     "make_async_copy",
     "make_async_remote_copy",
     "semaphore_signal",
     "semaphore_wait",
+    "enable_compile_cache",
 ]

@@ -1,105 +1,42 @@
-"""Feature detection for the installed JAX (probed once, at import).
+"""The Pallas handles and the version report of the installed JAX.
 
-Every probe is a ``hasattr``/signature check, never a version comparison,
-except for ``JAX_VERSION`` itself which is exposed for diagnostics and CI
-matrices.  The rest of the package keys off these booleans so a new JAX
-release that restores or renames an API is picked up without code changes.
-
-This module is the ONLY place in the repository that imports
+The repository targets one JAX release (jax/jaxlib 0.9.0, libtpu 0.0.34 —
+the versions of ``requirements-dev.txt``); nothing here probes for older
+spellings.  This module is the ONLY place in the repository that imports
 ``jax.experimental.pallas.tpu`` (enforced by tests/test_backend.py); the
 ``pl``/``pltpu`` handles re-exported here are consumed by the sibling
 modules and must not leak outside ``repro.backend``.
 """
 from __future__ import annotations
 
-import dataclasses
+from importlib import metadata
 
 import jax
+import jaxlib
 from jax.experimental import pallas as pl  # noqa: F401  (re-exported)
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (re-exported)
 
-__all__ = [
-    "JAX_VERSION",
-    "HAS_AXIS_TYPE",
-    "HAS_JAX_SHARD_MAP",
-    "HAS_JAX_MAKE_MESH",
-    "COMPILER_PARAMS_CLS",
-    "COMPILER_PARAMS_FIELDS",
-    "INTERPRET_PARAMS_CLS",
-    "HAS_TPU_INTERPRET_PARAMS",
-    "HAS_REMOTE_SIGNAL_IN_INTERPRET",
-    "MEMORY_SPACE_ANY",
-    "describe",
-    "pl",
-    "pltpu",
-]
+__all__ = ["describe", "pl", "pltpu"]
 
 
-def _version_tuple(v: str) -> tuple:
-    parts = []
-    for p in v.split("."):
-        digits = "".join(ch for ch in p if ch.isdigit())
-        if not digits:
-            break
-        parts.append(int(digits))
-    return tuple(parts)
-
-
-JAX_VERSION = _version_tuple(jax.__version__)
-
-# ---- mesh / shard_map surface ------------------------------------------------
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")  # >= 0.6
-HAS_JAX_SHARD_MAP = hasattr(jax, "shard_map")  # >= 0.7 public API
-HAS_JAX_MAKE_MESH = hasattr(jax, "make_mesh")  # >= 0.4.35
-
-
-def _probe(names, *modules):
-    """First attribute found under any of ``names`` on any module, else a
-    loud, actionable error (bare AttributeError at import would take down
-    even the non-Pallas paths with no hint where drift belongs)."""
-    for mod in modules:
-        for name in names:
-            found = getattr(mod, name, None)
-            if found is not None:
-                return found
-    raise ImportError(
-        f"none of {tuple(names)} found on this JAX ({jax.__version__}) — "
-        "add the new spelling to repro.backend.features"
-    )
-
-
-# ---- pallas TPU compiler params (CompilerParams <- TPUCompilerParams rename) --
-COMPILER_PARAMS_CLS = _probe(("CompilerParams", "TPUCompilerParams"), pltpu)
-COMPILER_PARAMS_FIELDS = frozenset(
-    f.name for f in dataclasses.fields(COMPILER_PARAMS_CLS)
-)
-
-# ---- TPU interpret mode ------------------------------------------------------
-# Newer JAX ships a dedicated TPU interpreter (pltpu.InterpretParams, earlier
-# pltpu.TPUInterpretParams) that simulates inter-device DMAs and semaphores.
-# Older JAX (0.4.x) instead discharges DMA/semaphore state in the generic
-# pallas interpreter when ``interpret=True`` — remote copies work there with a
-# scalar LOGICAL device id, but remote semaphore_signal does not.
-INTERPRET_PARAMS_CLS = getattr(pltpu, "InterpretParams", None) or getattr(
-    pltpu, "TPUInterpretParams", None
-)
-HAS_TPU_INTERPRET_PARAMS = INTERPRET_PARAMS_CLS is not None
-HAS_REMOTE_SIGNAL_IN_INTERPRET = HAS_TPU_INTERPRET_PARAMS
-
-MEMORY_SPACE_ANY = _probe(("ANY",), pl, pltpu)
+def _dist_version(name: str):
+    try:
+        return metadata.version(name)
+    except metadata.PackageNotFoundError:
+        return None
 
 
 def describe() -> dict:
-    """Snapshot of every probe, for logs / CI / bug reports."""
+    """Versions, the first device and the lowering target, for logs."""
+    from repro.backend.target import target
+
+    dev = jax.devices()[0]
     return {
-        "jax_version": jax.__version__,
-        "default_backend": jax.default_backend(),
-        "has_axis_type": HAS_AXIS_TYPE,
-        "has_jax_shard_map": HAS_JAX_SHARD_MAP,
-        "has_jax_make_mesh": HAS_JAX_MAKE_MESH,
-        "compiler_params_cls": COMPILER_PARAMS_CLS.__name__,
-        "interpret_params_cls": (
-            INTERPRET_PARAMS_CLS.__name__ if INTERPRET_PARAMS_CLS else None
-        ),
-        "has_remote_signal_in_interpret": HAS_REMOTE_SIGNAL_IN_INTERPRET,
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "libtpu": _dist_version("libtpu"),
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "device_count": jax.device_count(),
+        "target": target(),
     }

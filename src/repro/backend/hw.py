@@ -1,62 +1,96 @@
-"""Compute-hardware probes for tile-size tuning (MXU shape, VMEM budget).
+"""Per-chip hardware constants, keyed by ``device_kind`` (one table).
 
-The CompSpec half of the design space — the (tm, tn, tk) consumer-kernel
-tile — is only searchable if the tuner knows what the compute unit actually
-looks like: how wide the systolic array is (tiles below it waste MXU
-cycles), what the sublane/lane packing multiples are per dtype (misaligned
-tiles pad), and how much VMEM a tile's working set may occupy (oversized
-tiles spill or refuse to compile).  This module is the single place those
-constants live, probed per device kind with environment overrides, so
-``repro.tune.candidates`` prunes its tile lattice against the same numbers
-the kernels will face.
+The tuner prunes its tile lattice against the VMEM a kernel may use, the
+cost model and the roofline divide by the chip's peaks, and the kernels size
+their scoped-VMEM request.  All of them read :data:`CHIPS`.  A TPU whose
+``device_kind`` is not in the table is an error, never a default.  The
+emulated target (a CPU host running the kernels in the interpreter) models
+one named chip, :data:`EMULATED_KIND`, so tiles tuned there stay valid on it.
 
-Probing policy matches the rest of ``repro.backend``: inspect the live
-device (``device_kind``), fall back to conservative defaults on unknown or
-emulated hosts, never hard-code a version check.  ``REPRO_VMEM_BYTES``
-overrides the VMEM budget (tests use it to exercise the pruning path).
+Sources, TPU v5e ("TPU v5 lite" is its ``device_kind``):
+
+  * peaks — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+    16 GB HBM at 819 GB/s, 1,600 Gbit/s of ICI per chip (4 links, so
+    50 GB/s per link);
+  * VMEM — 128 MiB per TensorCore (``jax.experimental.pallas.tpu.
+    get_tpu_info()`` in jax 0.9.0); a kernel gets the compiler's default
+    *scoped* limit of 16 MiB unless it asks for more through
+    ``vmem_limit_bytes`` (JAX Pallas TPU documentation).
+
+``REPRO_VMEM_BYTES`` overrides the scoped budget (tests use it to exercise
+the pruning path).
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
 import jax.numpy as jnp
 
+from repro.backend.target import is_emulated
+
 __all__ = [
+    "Chip",
+    "CHIPS",
+    "EMULATED_KIND",
     "MXU_DIM",
     "LANE_MULTIPLE",
     "device_kind",
+    "chip",
     "mxu_dim",
     "vmem_budget_bytes",
+    "vmem_array_bytes",
+    "vmem_limit_bytes",
     "sublane_multiple",
     "lane_multiple",
 ]
 
 _ENV_VMEM = "REPRO_VMEM_BYTES"
 
-# the MXU systolic array is 128x128 on every shipped TPU generation; the
-# vector lane width (last-dim packing multiple) is likewise 128
+# the MXU systolic array is 128x128 on v5e; the vector lane width (last-dim
+# packing multiple) is likewise 128
 MXU_DIM = 128
 LANE_MULTIPLE = 128
 
-# VMEM per core by device kind (bytes).  ~16 MiB on v4/v5 parts, 32 MiB on
-# v6e; unknown kinds (CPU hosts running the emulated target) get the
-# conservative 16 MiB so tiles tuned on an emulated host stay valid on TPU.
-_VMEM_BY_KIND = {
-    "TPU v4": 16 * 2**20,
-    "TPU v5 lite": 16 * 2**20,
-    "TPU v5e": 16 * 2**20,
-    "TPU v5p": 16 * 2**20,
-    "TPU v6e": 32 * 2**20,
-    "TPU v6 lite": 32 * 2**20,
+
+@dataclasses.dataclass(frozen=True)
+class Chip:
+    peak_flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    link_bw: float  # bytes/s per ICI link
+    vmem_bytes: int  # physical VMEM per TensorCore
+    scoped_vmem_bytes: int  # default scoped-VMEM limit of one kernel
+
+
+CHIPS = {
+    "TPU v5 lite": Chip(
+        peak_flops=197e12,
+        hbm_bw=819e9,
+        link_bw=50e9,
+        vmem_bytes=128 * 2**20,
+        scoped_vmem_bytes=16 * 2**20,
+    ),
 }
-_DEFAULT_VMEM = 16 * 2**20
+EMULATED_KIND = "TPU v5 lite"
 
 
 def device_kind() -> str:
-    """Kind string of the first visible device ("cpu" on emulated hosts)."""
-    dev = jax.devices()[0]
-    return str(getattr(dev, "device_kind", dev.platform))
+    """The chip the program runs on, or :data:`EMULATED_KIND` on the emulated target."""
+    if is_emulated():
+        return EMULATED_KIND
+    return jax.devices()[0].device_kind
+
+
+def chip(kind=None) -> Chip:
+    """Constants of ``kind`` (default: :func:`device_kind`); unknown kinds raise."""
+    kind = kind or device_kind()
+    if kind not in CHIPS:
+        raise ValueError(
+            f"unknown TPU device_kind {kind!r}: add it to repro.backend.hw.CHIPS "
+            f"with its source (known: {sorted(CHIPS)})"
+        )
+    return CHIPS[kind]
 
 
 def mxu_dim() -> int:
@@ -65,11 +99,36 @@ def mxu_dim() -> int:
 
 
 def vmem_budget_bytes() -> int:
-    """VMEM available to one core's tile working set (env-overridable)."""
+    """VMEM one kernel's tile working set may use without asking for more."""
     env = os.environ.get(_ENV_VMEM)
     if env:
         return max(1, int(env))
-    return _VMEM_BY_KIND.get(device_kind(), _DEFAULT_VMEM)
+    return chip().scoped_vmem_bytes
+
+
+def vmem_array_bytes(shape, dtype) -> int:
+    """Bytes of one VMEM array, its last two dims padded to the (sublane, 128) tiling."""
+    *lead, rows, cols = (1,) + tuple(int(d) for d in shape)
+    sub = sublane_multiple(dtype)
+    rows = -(-rows // sub) * sub
+    cols = -(-cols // LANE_MULTIPLE) * LANE_MULTIPLE
+    n = rows * cols
+    for d in lead:
+        n *= d
+    return n * jnp.dtype(dtype).itemsize
+
+
+def vmem_limit_bytes(footprint: int):
+    """The scoped-VMEM request of a kernel whose buffers take ``footprint`` bytes.
+
+    None (the compiler's default) while it fits the smallest default scoped
+    limit in :data:`CHIPS`; else the footprint plus a quarter for Mosaic's
+    own scratch, in whole MiB.  The compiler refuses a request beyond the
+    chip's VMEM.
+    """
+    if footprint <= min(c.scoped_vmem_bytes for c in CHIPS.values()):
+        return None
+    return -(-(footprint * 5 // 4) // 2**20) * 2**20
 
 
 def sublane_multiple(dtype) -> int:

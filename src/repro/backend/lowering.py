@@ -1,25 +1,19 @@
 """Pallas lowering surface: the only path from kernels to ``pltpu``.
 
 Kernels and tile primitives call these functions instead of touching
-``jax.experimental.pallas.tpu`` — the rename churn (``CompilerParams`` /
-``TPUCompilerParams``, ``InterpretParams``), the interpret-mode capability
-differences, and the remote device-id representation are all absorbed here.
+``jax.experimental.pallas.tpu``, so the interpret-mode policy (see
+``target.py``) and the remote device-id representation live in one place.
 
 Remote device ids: every fused kernel addresses peers by *logical rank along
-the single manual mesh axis* it runs under.  On real TPUs that lowers to a
-MESH-coordinate device id (a 1-tuple); under the old-JAX generic interpreter
-the MESH tuple path is broken, but a scalar LOGICAL id is equivalent for one
-named axis and is what its discharge rule supports — ``_remote_device_id``
-picks per target/version so kernels never spell the representation.
+the single manual mesh axis* it runs under, which Mosaic takes as a
+MESH-coordinate device id (a 1-tuple).  The kernels are therefore launched
+under shard_map over a one-axis mesh.
 """
 from __future__ import annotations
-
-import inspect
 
 import jax.numpy as jnp
 
 from repro.backend import features as _f
-from repro.backend.target import is_emulated as _is_emulated
 from repro.backend.target import resolve_interpret as _resolve_interpret
 
 pl = _f.pl
@@ -27,7 +21,8 @@ pltpu = _f.pltpu
 
 __all__ = [
     "pl",
-    "ANY",
+    "HBM",
+    "SMEM",
     "compiler_params",
     "pallas_call",
     "prefetch_grid_spec",
@@ -35,43 +30,35 @@ __all__ = [
     "smem_scratch",
     "dma_semaphore",
     "regular_semaphore",
+    "barrier_semaphore",
     "make_async_copy",
     "make_async_remote_copy",
     "semaphore_signal",
     "semaphore_wait",
 ]
 
-ANY = _f.MEMORY_SPACE_ANY
+HBM = pltpu.HBM
+SMEM = pltpu.SMEM
 
 
 # ---- compile parameters ------------------------------------------------------
 
 def compiler_params(*, dimension_semantics=None, **kw):
-    """Build the TPU compiler-params object under its current name.
+    """``pltpu.CompilerParams``; unknown fields raise (a misspelled
+    ``vmem_limit_bytes`` must not be dropped in silence).
 
-    Unknown ``**kw`` keys (fields a given JAX doesn't have) are dropped
-    rather than erroring: they are tuning hints.  ``dimension_semantics`` is
-    NOT a hint — the fused ring kernels rely on "arbitrary" to force
-    sequential grid execution (each step waits on the previous step's DMA),
-    so if a JAX ever renames that field away we refuse loudly instead of
-    letting Mosaic parallelize the grid into deadlock/corruption.
+    The fused ring kernels rely on "arbitrary" ``dimension_semantics`` to
+    force sequential grid execution (each step waits on the previous step's
+    DMA).
     """
-    accepted = {k: v for k, v in kw.items() if k in _f.COMPILER_PARAMS_FIELDS}
     if dimension_semantics is not None:
-        if "dimension_semantics" not in _f.COMPILER_PARAMS_FIELDS:
-            raise NotImplementedError(
-                f"{_f.COMPILER_PARAMS_CLS.__name__} on this JAX has no "
-                "dimension_semantics field, which the kernels need for "
-                "correct grid ordering — add the new spelling to "
-                "repro.backend.lowering.compiler_params"
-            )
-        accepted["dimension_semantics"] = tuple(dimension_semantics)
-    return _f.COMPILER_PARAMS_CLS(**accepted)
+        kw["dimension_semantics"] = tuple(dimension_semantics)
+    return pltpu.CompilerParams(**kw)
 
 
 def pallas_call(kernel, *, dimension_semantics=None, interpret=None,
                 compiler_params_kw=None, **kw):
-    """``pl.pallas_call`` with version-normalized params and interpret mode.
+    """``pl.pallas_call`` with TPU compiler params and the target's interpret mode.
 
     ``interpret``: True/False, or None for "whatever the target needs"
     (the emulated target always interprets).
@@ -90,21 +77,12 @@ def pallas_call(kernel, *, dimension_semantics=None, interpret=None,
 def prefetch_grid_spec(*, num_scalar_prefetch, grid, in_specs, out_specs,
                        scratch_shapes=()):
     """Scalar-prefetch grid spec (dynamic-mapping kernels)."""
-    kw = dict(
+    return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalar_prefetch,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
-    )
-    if hasattr(pltpu, "PrefetchScalarGridSpec"):
-        return pltpu.PrefetchScalarGridSpec(**kw)
-    if "num_scalar_prefetch" in inspect.signature(pl.GridSpec).parameters:
-        return pl.GridSpec(**kw)
-    raise NotImplementedError(
-        "no scalar-prefetch grid spec found on this JAX (neither "
-        "pltpu.PrefetchScalarGridSpec nor a num_scalar_prefetch parameter on "
-        "pl.GridSpec) — add the new spelling to repro.backend.lowering"
     )
 
 
@@ -132,57 +110,13 @@ def regular_semaphore(shape=None):
     return pltpu.SemaphoreType.REGULAR(tuple(shape))
 
 
+def barrier_semaphore():
+    """The kernel's cross-device barrier semaphore (needs ``collective_id``
+    in the compiler params)."""
+    return pltpu.get_barrier_semaphore()
+
+
 # ---- DMA + semaphore primitives ----------------------------------------------
-
-def _remote_device_id(rank):
-    """(device_id, device_id_type) for a logical rank on the manual axis.
-
-    The LOGICAL spelling is only equivalent to the axis rank when the kernel
-    runs under exactly one named (manual) axis — which is how every fused
-    kernel here is launched.  With more named axes the old-JAX discharge rule
-    itself refuses (NotImplementedError at trace time), so the mismatch is
-    loud, never silent peer corruption.
-
-    On JAX without the TPU interpreter class, LOGICAL is used regardless of
-    target: any interpreted run there goes through the generic interpreter
-    (whose MESH-tuple path is broken), and for a single named axis Mosaic
-    accepts LOGICAL too, so it is the one spelling valid on every path.
-    Because logical id == axis rank only holds for ONE named axis, that
-    branch verifies the trace-time axis env and refuses otherwise — Mosaic
-    would compile the multi-axis case and silently DMA to the wrong peer.
-    """
-    if not _f.HAS_TPU_INTERPRET_PARAMS:
-        _check_single_named_axis()
-        return rank, pltpu.DeviceIdType.LOGICAL
-    return (rank,), pltpu.DeviceIdType.MESH
-
-
-def _check_single_named_axis():
-    # 0.4.x-only branch, so the 0.4.x-internal axis env is a safe probe.  If
-    # the probe API itself is missing, fail open only for interpreted runs
-    # (the generic interpreter's discharge rule refuses multi-axis LOGICAL on
-    # its own); for a Mosaic compile there is no second line of defense
-    # against wrong-peer DMAs, so refuse instead.
-    try:
-        from jax._src import core as _jax_core
-
-        named = [n for n in _jax_core.get_axis_env().axis_sizes if n is not None]
-    except (ImportError, AttributeError):
-        if _is_emulated():
-            return
-        raise NotImplementedError(
-            "cannot verify the manual-axis count on this JAX (private axis-env "
-            "probe missing) and Mosaic would silently accept a wrong logical "
-            "device id — add the new probe spelling to repro.backend.lowering"
-        ) from None
-    if len(named) > 1:
-        raise NotImplementedError(
-            f"remote DMA by logical rank under {len(named)} named axes "
-            f"{tuple(named)}: on this JAX the logical device id equals the "
-            "axis rank only for a single manual axis — launch the fused "
-            "kernel under shard_map over just the channel axis"
-        )
-
 
 def make_async_copy(src_ref, dst_ref, sem):
     """Local async copy handle (start()/wait())."""
@@ -191,14 +125,13 @@ def make_async_copy(src_ref, dst_ref, sem):
 
 def make_async_remote_copy(src_ref, dst_ref, send_sem, recv_sem, rank):
     """Remote async copy handle addressed by logical rank on the manual axis."""
-    device_id, device_id_type = _remote_device_id(rank)
     return pltpu.make_async_remote_copy(
         src_ref=src_ref,
         dst_ref=dst_ref,
         send_sem=send_sem,
         recv_sem=recv_sem,
-        device_id=device_id,
-        device_id_type=device_id_type,
+        device_id=(rank,),
+        device_id_type=pltpu.DeviceIdType.MESH,
     )
 
 
@@ -207,17 +140,8 @@ def semaphore_signal(sem, inc: int = 1, *, rank=None):
     if rank is None:
         pltpu.semaphore_signal(sem, inc)
         return
-    if _is_emulated() and not _f.HAS_REMOTE_SIGNAL_IN_INTERPRET:
-        raise NotImplementedError(
-            "remote semaphore_signal is not simulated by the generic pallas "
-            f"interpreter on jax {'.'.join(map(str, _f.JAX_VERSION))}; "
-            "structure the kernel around make_async_remote_copy recv "
-            "semaphores (as ag_gemm/gemm_rs do), or run on a JAX with "
-            "pltpu.InterpretParams for full emulation"
-        )
-    device_id, device_id_type = _remote_device_id(rank)
     pltpu.semaphore_signal(
-        sem, inc, device_id=device_id, device_id_type=device_id_type
+        sem, inc, device_id=(rank,), device_id_type=pltpu.DeviceIdType.MESH
     )
 
 

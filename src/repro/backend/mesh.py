@@ -1,89 +1,28 @@
-"""Version-stable mesh construction and shard_map entry.
-
-Drift handled here:
-
-  * ``jax.make_mesh`` gained ``axis_types`` (``jax.sharding.AxisType``) in
-    0.6; on 0.4.x referencing ``AxisType`` raises AttributeError — probe
-    with ``hasattr`` first instead of relying on exception type.
-  * ``jax.shard_map`` became public API in 0.7 with ``check_vma`` and
-    ``axis_names`` (partial-auto); before that it lives in
-    ``jax.experimental.shard_map`` with ``check_rep`` and the *complement*
-    parameter ``auto`` (the set of axes that stay automatic).
-"""
+"""Mesh construction and shard_map entry."""
 from __future__ import annotations
 
 import jax
 from jax import lax
 
-from repro.backend import features as _f
-
 __all__ = ["make_mesh", "shard_map", "axis_size"]
 
-
-def axis_size(name):
-    """Size of a named mesh axis from inside a manual region.
-
-    ``lax.axis_size`` appeared after 0.4.x; ``psum(1, axis)`` is the
-    version-stable spelling (constant-folded, works inside Pallas kernels too).
-    """
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return lax.psum(1, name)
+axis_size = lax.axis_size
 
 
 def make_mesh(shape, axis_names):
     """Mesh constructor pinned to Auto axis types (we use in_shardings/constraints)."""
-    if _f.HAS_AXIS_TYPE:
-        try:
-            return jax.make_mesh(
-                shape,
-                axis_names,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-            )
-        except TypeError:
-            pass  # make_mesh predates axis_types
-    if _f.HAS_JAX_MAKE_MESH:
-        return jax.make_mesh(shape, axis_names)
-    from jax.experimental import mesh_utils
-
-    return jax.sharding.Mesh(mesh_utils.create_device_mesh(shape), axis_names)
+    return jax.make_mesh(
+        shape, axis_names, axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names)
+    )
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_rep: bool = False,
-              axis_names=None):
-    """Version-stable shard_map wrapper (check_rep/check_vma naming drift).
+def shard_map(f, mesh, in_specs, out_specs, check_rep: bool = False, axis_names=None):
+    """``jax.shard_map`` with replication checks off by default.
 
     ``axis_names``: when given, a partial-auto shard_map — only those mesh axes
     are manual; the rest stay under the automatic partitioner.
     """
-    if _f.HAS_JAX_SHARD_MAP:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        try:
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check_rep, **kw,
-            )
-        except TypeError:
-            pass  # transitional releases: fall through to the experimental API
-    from jax.experimental.shard_map import shard_map as _sm
-
-    kw = {}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto and _f.HAS_AXIS_TYPE:
-            # pre-0.7 spelling of partial-auto: pass the *auto* complement
-            kw["auto"] = auto
-        # On 0.4.x partial-auto is broken in XLA:CPU SPMD (axis_index lowers
-        # to an unsupported PartitionId instruction), so fall through to a
-        # full-manual region instead: specs mention only the manual axes, so
-        # the body sees the same shapes, with the other axes replicated —
-        # identical results, redundant compute on the unmentioned axes, and
-        # the shard_map transpose still psums cotangents over them (DP grads).
-        # Caveat: fused remote-DMA kernels cannot run inside this fallback on
-        # a multi-axis mesh — all axes become named, and the logical-rank
-        # device-id check in lowering.py refuses >1 named axis (loudly, at
-        # trace time). The XLA overlap path (what smap callers use) is fine.
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep, **kw)
+    kw = {} if axis_names is None else {"axis_names": frozenset(axis_names)}
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_rep, **kw
+    )

@@ -3,12 +3,15 @@
 Targets:
 
   "tpu"       lower Pallas kernels to Mosaic; remote DMAs ride the ICI.
-  "emulated"  force ``interpret`` execution so every kernel — including the
-              fused communication kernels — runs on any host with no TPU,
-              using XLA's forced-host-device pool for the mesh axes.
+  "emulated"  run every kernel — including the fused communication kernels —
+              in the TPU interpreter on a host with no TPU, using XLA's
+              forced-host-device pool for the mesh axes.
 
 Resolution order: the ``REPRO_BACKEND`` environment variable ("tpu",
-"emulated", or "auto"), else "tpu" iff ``jax.default_backend() == "tpu"``.
+"emulated", or "auto"); "auto" is "tpu" iff ``jax.default_backend() ==
+"tpu"``.  A host without a TPU has no Mosaic runtime, so "auto" can only
+mean the interpreter there; code that must run on the chip (``chip_smoke.py``)
+checks ``target() == "tpu"`` and refuses anything else.
 """
 from __future__ import annotations
 
@@ -16,7 +19,7 @@ import os
 
 import jax
 
-from repro.backend import features as _f
+from repro.backend.features import pltpu
 
 __all__ = ["target", "is_emulated", "resolve_interpret", "default_interpret"]
 
@@ -43,26 +46,19 @@ def is_emulated() -> bool:
 def resolve_interpret(interpret=None):
     """Normalize an ``interpret`` request into what pallas_call accepts here.
 
-    ``None`` means "whatever the target needs" (emulated -> interpret).  On
-    JAX with the dedicated TPU interpreter, interpreting returns an
-    ``InterpretParams`` instance (it simulates inter-device DMAs); on older
-    JAX it returns plain ``True`` (the generic interpreter's discharge rules
-    cover local and single-axis remote DMAs).
+    ``None`` means "whatever the target needs": Mosaic on the "tpu" target,
+    the TPU interpreter (``pltpu.InterpretParams``, which simulates the
+    inter-device DMAs and semaphores) on the "emulated" target.  The
+    emulated target has no Mosaic compiler, so an explicit ``False`` there
+    interprets too.
     """
     if interpret is None:
         interpret = is_emulated()
     if isinstance(interpret, bool):
-        if not interpret:
-            # The emulated target has no Mosaic compiler to fall back to:
-            # compiling is not an option, so interpret anyway.
-            if is_emulated():
-                interpret = True
-            else:
-                return False
-        if _f.INTERPRET_PARAMS_CLS is not None:
-            return _f.INTERPRET_PARAMS_CLS()
-        return True
-    return interpret  # already an InterpretParams-like object
+        if not interpret and not is_emulated():
+            return False
+        return pltpu.InterpretParams()
+    return interpret  # already an InterpretParams object
 
 
 def default_interpret() -> bool:

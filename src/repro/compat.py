@@ -1,9 +1,7 @@
-"""Legacy compatibility surface — thin re-export of ``repro.backend``.
+"""Re-export of ``repro.backend``'s mesh helpers and ``jax.tree_util``.
 
-Historically this module held the JAX version shims; they now live in the
-``repro.backend`` package (single point of version adaptation).  Kept so
-existing imports (``from repro.compat import shard_map, make_mesh``) keep
-working; new code should import ``repro.backend`` directly.
+Kept so existing imports (``from repro.compat import shard_map, make_mesh``)
+keep working; new code should import ``repro.backend`` directly.
 """
 from __future__ import annotations
 

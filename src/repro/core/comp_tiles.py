@@ -32,6 +32,9 @@ from repro.core.quant import PackedWeight, dequantize_weight
 __all__ = [
     "DEFAULT_TILE",
     "largest_divisor",
+    "round_up",
+    "pad2",
+    "lane_block",
     "resolve_tile",
     "blocked_dot",
     "tile_footprint_bytes",
@@ -61,6 +64,47 @@ def largest_divisor(extent: int, cap: int) -> int:
                 best = pair
         d += 1
     return best
+
+
+def round_up(n: int, multiple: int = 128) -> int:
+    return -(-int(n) // multiple) * multiple
+
+
+def pad2(a, rows: int, cols: int):
+    """Zero-pad a 2-D operand (or a PackedWeight, whose padded columns
+    dequantize to 0) to [rows, cols].
+
+    Mosaic slices a VMEM or HBM ref only when its last dim is a multiple of
+    128, so the fused kernels pad their operands' lane dims to 128 and drop
+    the padded output columns.
+    """
+    r, c = a.shape
+    if (r, c) == (rows, cols):
+        return a
+    if isinstance(a, PackedWeight):
+        vec = (0, cols - c)
+        return PackedWeight(
+            pad2(a.q, rows, cols), jnp.pad(a.scale, vec),
+            None if a.zero is None else jnp.pad(a.zero, vec), a.dtype)
+    return jnp.pad(a, ((0, rows - r), (0, cols - c)))
+
+
+def lane_block(extent: int, want: int, *, what: str) -> int:
+    """Lane-dim (last-dim) block of a Pallas TPU BlockSpec over ``extent``.
+
+    Mosaic slices a lane dim only in multiples of 128.  Returns the largest
+    multiple of 128 that divides ``extent`` and is <= ``max(want, 128)``;
+    raises a NotImplementedError naming ``what`` when ``extent`` is off the
+    128 grid.
+    """
+    lane = 128
+    if extent % lane:
+        raise NotImplementedError(
+            f"{what}: a lane block over {extent} columns must be a multiple of "
+            "128 that divides them; none does"
+        )
+    cap = min(max(int(want), lane), extent)
+    return max(b for b in range(lane, cap + 1, lane) if extent % b == 0)
 
 
 def resolve_tile(tile: Tuple[int, int, int], m: int, n: int, k: int) -> Tuple[int, int, int]:

@@ -35,6 +35,7 @@ __all__ = [
     "peer_tile_wait",
     "tile_push_data",
     "make_tile_push",
+    "rank_barrier",
 ]
 
 
@@ -55,6 +56,22 @@ def consumer_tile_wait(sem, *, count: int = 1):
 # peers are the same mechanism on a dedicated peer channel (paper Fig. 4 ring)
 peer_tile_notify = producer_tile_notify
 peer_tile_wait = consumer_tile_wait
+
+
+def rank_barrier(my, world: int):
+    """Every rank of the axis has entered the kernel once this returns.
+
+    A remote DMA lands in the peer's VMEM scratch and signals its semaphore,
+    which exist only while the peer runs this kernel; pushing before the
+    peer entered it would corrupt whatever the peer ran before and lose the
+    signal.  So kernels that push call this first: each rank signals the
+    barrier semaphore of every other rank and waits for ``world - 1``
+    signals (the kernel's compiler params need a ``collective_id``).
+    """
+    sem = backend.barrier_semaphore()
+    for d in range(1, world):
+        backend.semaphore_signal(sem, 1, rank=(my + d) % world)
+    backend.semaphore_wait(sem, world - 1)
 
 
 def make_tile_push(src_ref, dst_ref, send_sem, recv_sem, rank):

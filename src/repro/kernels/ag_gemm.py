@@ -42,7 +42,8 @@ from repro import backend
 from repro.backend import pl
 from repro.core import primitives
 from repro.core.channels import BlockChannel
-from repro.core.comp_tiles import DEFAULT_TILE, blocked_dot, largest_divisor
+from repro.core.comp_tiles import (
+    DEFAULT_TILE, blocked_dot, lane_block, largest_divisor, pad2, round_up)
 from repro.core.mapping import effective_channels
 from repro.core.plan import build_plan
 from repro.core.quant import PackedWeight
@@ -82,6 +83,13 @@ def _ag_gemm_kernel(
     src = src_tbl[flat]  # origin (== gather slot) consumed this step
     dst = dst_tbl[flat]  # peer the held tile is forwarded to
     slot = src * nch + c
+
+    if world > 1:
+
+        @pl.when((s == 0) & (c == 0) & (j == 0))
+        def _enter():
+            # no peer pushes into our gather buffer before we run this kernel
+            primitives.rank_barrier(my, world)
 
     @pl.when(jnp.logical_and(s == 0, j == 0))
     def _local_seed():
@@ -149,7 +157,7 @@ def ag_gemm_shard(
     channel: Optional[BlockChannel] = None,
     world_size: int,
     bn: Optional[int] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Per-shard fused AG+GEMM. x: [m_loc, K], w: [K, n_loc] -> [R*m_loc, n_loc].
 
@@ -163,10 +171,13 @@ def ag_gemm_shard(
     VMEM right before the MXU.  Quantized *activation* wires
     (``channel.quant.wire_dtype`` int8/fp8) are XLA-backend only — the scale
     side-channel per remote DMA is not plumbed here; this raises rather than
-    silently sending unscaled codes.  ``interpret=True`` runs the
-    interpreter (CPU validation); False lowers to Mosaic on TPU hosts — on a
-    CPU-only host the emulated backend target interprets regardless, since
-    there is no Mosaic toolchain to compile with.
+    silently sending unscaled codes.  ``interpret=None`` lets the backend
+    target decide (Mosaic on "tpu", the interpreter on "emulated").
+
+    The lane block ``bn`` is a multiple of 128 dividing ``n_loc`` padded to
+    128 (:func:`~repro.core.comp_tiles.lane_block`).  The whole
+    gathered operand ``[world * m_loc, K]`` sits in VMEM, which bounds the
+    widths this kernel takes (see ROADMAP).
     """
     channel = channel or BlockChannel(axis="model")
     if channel.quant.is_quantized:
@@ -178,10 +189,13 @@ def ag_gemm_shard(
     axis = channel.axis
     m_loc, k = x.shape
     packed = isinstance(w, PackedWeight)
-    _, n_loc = w.shape
+    n_out = w.shape[1]
+    # lane dims padded to 128 (Mosaic slices no other ref); the padded
+    # contraction rows are zero, the padded output columns are dropped
+    k, n_loc = round_up(k), round_up(n_out)
+    x, w = pad2(x, m_loc, k), pad2(w, k, n_loc)
     comp_tile = tuple(channel.comp.tile)
-    bn = bn or comp_tile[1]
-    bn = largest_divisor(n_loc, bn)
+    bn = lane_block(n_loc, bn or comp_tile[1], what=f"ag_gemm n_loc={n_out}")
     n_tiles = n_loc // bn
 
     nch = effective_channels(m_loc, channel.num_channels, kind="ag_matmul")
@@ -212,7 +226,7 @@ def ag_gemm_shard(
         packed=packed,
     )
     in_specs = [
-        pl.BlockSpec(memory_space=backend.ANY),
+        pl.BlockSpec(memory_space=backend.HBM),
         pl.BlockSpec((k, bn), lambda s, c, j: (0, j)),
     ]
     operands = [x]
@@ -229,26 +243,35 @@ def ag_gemm_shard(
     else:
         operands.append(w)
     in_specs.extend([
-        pl.BlockSpec(memory_space=backend.ANY),  # src schedule table
-        pl.BlockSpec(memory_space=backend.ANY),  # dst schedule table
+        pl.BlockSpec(memory_space=backend.SMEM),  # src schedule table
+        pl.BlockSpec(memory_space=backend.SMEM),  # dst schedule table
     ])
     operands.extend([src_tbl, dst_tbl])
-    return backend.pallas_call(
+    vmem = [
+        ((world_size * nch, m_sub, k), x.dtype),  # gather
+        ((m_sub, k), x.dtype),  # current tile
+        ((m_sub, bn), accum),  # accumulator
+        ((m_sub, bn), x.dtype),  # cast staging tile
+    ]
+    # the pipelined (k, bn) weight block (+ its scale/zero rows) is double-buffered
+    blocks = [((k, bn), operands[1].dtype)] + [((1, bn), jnp.float32)] * (2 * packed)
+    footprint = sum(backend.vmem_array_bytes(*a) for a in vmem) + 2 * sum(
+        backend.vmem_array_bytes(*b) for b in blocks)
+    out = backend.pallas_call(
         kern,
         grid=(world_size, nch, n_tiles),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=backend.ANY),
+        out_specs=pl.BlockSpec(memory_space=backend.HBM),
         out_shape=jax.ShapeDtypeStruct((world_size * m_loc, n_loc), x.dtype),
-        scratch_shapes=[
-            backend.vmem_scratch((world_size * nch, m_sub, k), x.dtype),  # gather
-            backend.vmem_scratch((m_sub, k), x.dtype),  # current tile
-            backend.vmem_scratch((m_sub, bn), accum),  # accumulator
-            backend.vmem_scratch((m_sub, bn), x.dtype),  # cast staging tile
+        scratch_shapes=[backend.vmem_scratch(*a) for a in vmem] + [
             backend.dma_semaphore(),  # local copies
             backend.dma_semaphore(),  # sends
             backend.dma_semaphore((world_size * nch,)),  # per-(step, ch) recv
             backend.dma_semaphore(),  # out stores
         ],
         dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+        compiler_params_kw=dict(
+            collective_id=0, vmem_limit_bytes=backend.vmem_limit_bytes(footprint)),
         interpret=interpret,
     )(*operands)
+    return out[:, :n_out] if n_out != n_loc else out

@@ -30,9 +30,10 @@ straight from the accumulator's channel columns, guarded by ``wait_send``
 overwritten next stage (a shared send semaphore makes the release credits of
 concurrent channels interchangeable — a WAR race ``repro.analysis`` flags).
 
-VMEM budget: the flowing accumulator is [m_loc, N] resident in VMEM; pick
-m_loc * N * 4B ≲ 4 MiB per call (the TP shard sizes used by the models obey
-this; larger N is tiled by the caller over column blocks).
+VMEM budget: the flowing accumulator [m_loc, N] and the world * C received
+partials are resident in VMEM (about 16 MiB in f32 at smollm-360m's TP=4
+down-projection, m_loc=512, N=960); the kernel asks the compiler for the
+scoped VMEM its buffers take (``backend.vmem_limit_bytes``).
 """
 from __future__ import annotations
 
@@ -47,7 +48,8 @@ from repro import backend
 from repro.backend import pl
 from repro.core import primitives
 from repro.core.channels import BlockChannel
-from repro.core.comp_tiles import DEFAULT_TILE, blocked_dot, largest_divisor
+from repro.core.comp_tiles import (
+    DEFAULT_TILE, blocked_dot, lane_block, largest_divisor, pad2, round_up)
 from repro.core.mapping import effective_channels
 from repro.core.plan import build_plan
 from repro.core.quant import PackedWeight
@@ -91,6 +93,18 @@ def _gemm_rs_kernel(
     flat = (c * world + s) * world + my
     seg = seg_tbl[flat]  # segment this rank reduces at stage s
     dst = dst_tbl[flat]  # peer that reduces it at stage s+1
+    # lane offsets of this channel's and this tile's columns: multiples of
+    # 128, or a static 0 when one block spans the whole width
+    c0 = pl.multiple_of(c * n_sub, 128) if nch > 1 else 0
+    col = pl.multiple_of(c0 + j * bn, 128) if n_tiles > 1 or nch > 1 else 0
+    jb = pl.multiple_of(j * bn, 128) if n_tiles > 1 else 0
+
+    if world > 1:
+
+        @pl.when((s == 0) & (c == 0) & (j == 0))
+        def _enter():
+            # no peer pushes into our receive buffers before we run this kernel
+            primitives.rank_barrier(my, world)
 
     def _push_rdma(stage):
         # identical descriptor on sender & receiver (SPMD) — sender start()s,
@@ -105,7 +119,7 @@ def _gemm_rs_kernel(
         if split:
             src = send_buf.at[pl.ds(c * m_loc, m_loc), :]
         else:
-            src = acc.at[:, pl.ds(c * n_sub, n_sub)]
+            src = acc.at[:, pl.ds(c0, n_sub)]
         return primitives.make_tile_push(
             src_ref=src,
             dst_ref=rbuf.at[stage * nch + c],
@@ -149,11 +163,10 @@ def _gemm_rs_kernel(
         # 1/2-1/4 the bytes; scales/zeros are per output column
         w_val = (w_val.astype(accum) - zero_ref[0, :][None, :]) * scale_ref[0, :][None, :]
     part = blocked_dot(x_vmem[...], w_val, (tm, bn, tk), accum=accum, unroll=True)
-    col = c * n_sub + j * bn
 
     @pl.when(s > 0)
     def _add_prev():
-        acc[:, pl.ds(col, bn)] = part + prev[:, pl.ds(j * bn, bn)].astype(part.dtype)
+        acc[:, pl.ds(col, bn)] = part + prev[:, pl.ds(jb, bn)].astype(part.dtype)
 
     @pl.when(s == 0)
     def _no_prev():
@@ -168,14 +181,14 @@ def _gemm_rs_kernel(
                 # stage-(s-1) push from these rows drained at this stage's
                 # j == 0 wait_send
                 send_buf[pl.ds(c * m_loc, m_loc), :] = (
-                    acc[:, pl.ds(c * n_sub, n_sub)].astype(send_buf.dtype))
+                    acc[:, pl.ds(c0, n_sub)].astype(send_buf.dtype))
             _push_rdma(s).start()  # tile_push_data + peer_tile_notify
 
         @pl.when(s == world - 1)
         def _store():
             # paper lines 22-23: final stage stores the reduced home segment
-            out_cast[...] = acc[:, pl.ds(c * n_sub, n_sub)].astype(out_cast.dtype)
-            cp = backend.make_async_copy(out_cast, o_ref.at[:, pl.ds(c * n_sub, n_sub)], copy_sem)
+            out_cast[...] = acc[:, pl.ds(c0, n_sub)].astype(out_cast.dtype)
+            cp = backend.make_async_copy(out_cast, o_ref.at[:, pl.ds(c0, n_sub)], copy_sem)
             cp.start()
             cp.wait()
 
@@ -187,7 +200,7 @@ def gemm_rs_shard(
     channel: Optional[BlockChannel] = None,
     world_size: int,
     bn: Optional[int] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Per-shard fused GEMM+RS. x: [M, k_loc], w: [k_loc, N] -> [M/R, N].
 
@@ -201,8 +214,12 @@ def gemm_rs_shard(
     weight blocks stream HBM->VMEM as integer codes and are dequantized in
     VMEM right before the MXU.  Quantized *activation* wires (int8/fp8) are
     XLA-backend only — the scale side-channel per remote DMA is not plumbed
-    here.  ``interpret=False`` lowers to Mosaic only on TPU hosts — on a
-    CPU-only host the emulated backend target interprets regardless.
+    here.  ``interpret=None`` lets the backend target decide (Mosaic on
+    "tpu", the interpreter on "emulated").
+
+    The lane block ``bn`` is a multiple of 128 dividing the channel width
+    ``N / C``, with ``N`` padded to 128
+    (:func:`~repro.core.comp_tiles.lane_block`); other (N, C) pairs raise.
     """
     channel = channel or BlockChannel(axis="model")
     if channel.quant.is_quantized:
@@ -214,16 +231,19 @@ def gemm_rs_shard(
     axis = channel.axis
     m_glob, k_loc = x.shape
     packed = isinstance(w, PackedWeight)
-    _, n = w.shape
+    n_out = w.shape[1]
     assert m_glob % world_size == 0
     m_loc = m_glob // world_size
+    # lane dims padded to 128 (Mosaic slices no other ref); the padded
+    # contraction rows are zero, the padded output columns are dropped
+    k_loc, n = round_up(k_loc), round_up(n_out)
+    x, w = pad2(x, m_glob, k_loc), pad2(w, k_loc, n)
 
     nch = effective_channels(n, channel.num_channels, kind="matmul_rs")
     plan = build_plan("matmul_rs", channel, world_size, nch)
     n_sub = n // nch
     comp_tile = tuple(channel.comp.tile)
-    bn = bn or comp_tile[1]
-    bn = largest_divisor(n_sub, bn)
+    bn = lane_block(n_sub, bn or comp_tile[1], what=f"gemm_rs N={n_out} with num_channels={nch}")
     n_tiles = n_sub // bn
     if comp_tile == DEFAULT_TILE:
         # sentinel: backend-chosen blocking — whole-segment rows/contraction
@@ -253,7 +273,7 @@ def gemm_rs_shard(
         split=split,
     )
     in_specs = [
-        pl.BlockSpec(memory_space=backend.ANY),
+        pl.BlockSpec(memory_space=backend.HBM),
         pl.BlockSpec((k_loc, bn), lambda s, c, j: (0, c * (n_sub // bn) + j)),
     ]
     operands = [x]
@@ -270,30 +290,39 @@ def gemm_rs_shard(
     else:
         operands.append(w)
     in_specs.extend([
-        pl.BlockSpec(memory_space=backend.ANY),  # segment schedule table
-        pl.BlockSpec(memory_space=backend.ANY),  # push-dst schedule table
+        pl.BlockSpec(memory_space=backend.SMEM),  # segment schedule table
+        pl.BlockSpec(memory_space=backend.SMEM),  # push-dst schedule table
     ])
     operands.extend([seg_tbl, dst_tbl])
-    scratch = [
-        backend.vmem_scratch((m_loc, k_loc), x.dtype),  # x segment
-        backend.vmem_scratch((m_loc, n), accum),  # stage accumulator
-        backend.vmem_scratch((m_loc, n_sub), wire),  # received partial
-        backend.vmem_scratch((m_loc, n_sub), x.dtype),  # final cast
+    vmem = [
+        ((m_loc, k_loc), x.dtype),  # x segment
+        ((m_loc, n), accum),  # stage accumulator
+        ((m_loc, n_sub), wire),  # received partial
+        ((m_loc, n_sub), x.dtype),  # final cast
+    ]
+    rbuf = ((world_size * nch, m_loc, n_sub), wire)
+    # per-channel wire-dtype send staging (rows c*m_loc:(c+1)*m_loc)
+    staging = [((nch * m_loc, n_sub), wire)] if split else []
+    scratch = [backend.vmem_scratch(*a) for a in vmem] + [
         backend.dma_semaphore(),  # local copies
         backend.dma_semaphore((nch,)),  # per-channel sends (release order)
         backend.dma_semaphore((world_size * nch,)),  # per-(stage,ch) recv
-        backend.vmem_scratch((world_size * nch, m_loc, n_sub), wire),  # rbuf
-    ]
-    if split:
-        # per-channel wire-dtype send staging (rows c*m_loc:(c+1)*m_loc)
-        scratch.append(backend.vmem_scratch((nch * m_loc, n_sub), wire))
-    return backend.pallas_call(
+        backend.vmem_scratch(*rbuf),
+    ] + [backend.vmem_scratch(*a) for a in staging]
+    # the pipelined (k_loc, bn) weight block (+ its scale/zero rows) is double-buffered
+    blocks = [((k_loc, bn), operands[1].dtype)] + [((1, bn), jnp.float32)] * (2 * packed)
+    footprint = sum(backend.vmem_array_bytes(*a) for a in vmem + [rbuf] + staging) + 2 * sum(
+        backend.vmem_array_bytes(*b) for b in blocks)
+    out = backend.pallas_call(
         kern,
         grid=(world_size, nch, n_tiles),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=backend.ANY),
+        out_specs=pl.BlockSpec(memory_space=backend.HBM),
         out_shape=jax.ShapeDtypeStruct((m_loc, n), x.dtype),
         scratch_shapes=scratch,
         dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+        compiler_params_kw=dict(
+            collective_id=0, vmem_limit_bytes=backend.vmem_limit_bytes(footprint)),
         interpret=interpret,
     )(*operands)
+    return out[:, :n_out] if n_out != n else out

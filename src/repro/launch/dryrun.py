@@ -8,6 +8,10 @@ os.environ["XLA_FLAGS"] += " --xla_disable_hlo_passes=all-reduce-promotion"
 meshes, print memory/cost analysis, and derive roofline terms.
 
 The two lines above MUST stay first: jax locks the device count on first init.
+Never point this module at a TPU: it forces 512 CPU devices at import and
+spawns one child process per cell, and a chip belongs to one process.  The
+roofline terms it prints model TPU v5e pods (``DRYRUN_KIND``); they are
+estimates from the compiler's cost analysis, not measurements.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen2-72b --shape train_4k            # one cell
@@ -21,6 +25,8 @@ import sys
 import time
 
 __all__ = ["run_cell", "main"]
+
+DRYRUN_KIND = "TPU v5 lite"  # the chip the production meshes are made of
 
 
 def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
@@ -191,7 +197,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
              + (n_units - u1) * (c2["kinds"].get(k, 0.0) - c1["kinds"].get(k, 0.0))
              for k in set(c1["kinds"]) | set(c2["kinds"])}
 
-    terms = R.roofline_terms({"flops": flops, "bytes accessed": byts}, coll)
+    terms = R.roofline_terms({"flops": flops, "bytes accessed": byts}, coll, kind=DRYRUN_KIND)
     n_chips = 512 if multi_pod else 256
     mf = R.model_flops(cfg, shape)
     useful = mf / max(flops * n_chips, 1.0)

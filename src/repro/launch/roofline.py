@@ -1,4 +1,4 @@
-"""Roofline-term derivation from compiled dry-run artifacts (TPU v5e model).
+"""Roofline-term derivation from compiled dry-run artifacts, per chip kind.
 
   compute term    = HLO_FLOPs_per_device / peak_FLOP/s
   memory term     = HLO_bytes_per_device / HBM_bw
@@ -20,13 +20,14 @@ import re
 from collections import defaultdict
 from typing import Dict, Tuple
 
+from repro.backend import hw as _hw
+
 __all__ = ["HW", "parse_collective_bytes", "roofline_terms", "model_flops"]
 
-HW = {
-    "peak_flops": 197e12,  # bf16 TFLOP/s per chip (v5e)
-    "hbm_bw": 819e9,  # B/s per chip
-    "link_bw": 50e9,  # B/s per ICI link
-}
+# Published peaks per chip keyed by jax ``device_kind`` (bf16 FLOP/s, HBM
+# bytes/s, bytes/s per ICI link), with their source: the one hardware table.
+HW = _hw.CHIPS
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -110,14 +111,16 @@ def parse_collective_bytes(hlo_text: str) -> Tuple[float, Dict[str, float]]:
     return non_permute + permute_link, dict(per_kind)
 
 
-def roofline_terms(cost: dict, collective_bytes: float) -> Dict[str, float]:
-    """Three roofline terms (seconds) from per-device cost analysis."""
+def roofline_terms(cost: dict, collective_bytes: float, kind: str) -> Dict[str, float]:
+    """Three roofline terms (seconds) on a ``kind`` chip from per-device cost
+    analysis; a kind not in :data:`HW` raises."""
+    hw = _hw.chip(kind)
     flops = float(cost.get("flops", 0.0) or 0.0)
     byts = float(cost.get("bytes accessed", 0.0) or 0.0)
     return {
-        "compute_s": flops / HW["peak_flops"],
-        "memory_s": byts / HW["hbm_bw"],
-        "collective_s": collective_bytes / HW["link_bw"],
+        "compute_s": flops / hw.peak_flops,
+        "memory_s": byts / hw.hbm_bw,
+        "collective_s": collective_bytes / hw.link_bw,
         "flops": flops,
         "bytes": byts,
         "collective_bytes": collective_bytes,

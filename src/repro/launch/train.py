@@ -16,6 +16,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro import backend
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data import SyntheticLM
@@ -49,11 +50,20 @@ def reduce_config(cfg, d_model=128, vocab=512):
     return dataclasses.replace(cfg, **kw)
 
 
-def train(arch: str, *, steps=100, batch=8, seq=256, reduce=True,
+def train(arch, *, steps=100, batch=8, seq=256, reduce=True,
           mode="overlap", ckpt_dir=None, ckpt_every=50, lr=3e-4,
           production_mesh=False, dtype=jnp.float32, log_every=10,
-          resume=True):
-    cfg = get_config(arch)
+          resume=True, on_step=None, remat_policy="dots"):
+    """Train ``arch`` (a registered name or an ArchConfig); returns the losses.
+
+    ``on_step(step, metrics)``, when given, sees each step's metrics as
+    Python floats (loss, lr, grad_norm, ...).  ``remat_policy`` is
+    ``lm.forward``'s: "dots" saves the matmul outputs, "full" recomputes
+    each layer from its input (full-width smollm-360m in f32 at 8 x 1024
+    tokens needs it to fit one 16 GB v5e).
+    """
+    cfg = get_config(arch) if isinstance(arch, str) else arch
+    arch = cfg.name
     if reduce:
         cfg = reduce_config(cfg)
     mod = model_module(cfg)
@@ -71,7 +81,7 @@ def train(arch: str, *, steps=100, batch=8, seq=256, reduce=True,
 
     opt_cfg = AdamWConfig(lr=lr, total_steps=steps, warmup_steps=max(5, steps // 20))
     masks = mod.grad_masks(cfg, pc)
-    step_fn = make_train_step(mod, cfg, pc, opt_cfg, remat_policy="dots",
+    step_fn = make_train_step(mod, cfg, pc, opt_cfg, remat_policy=remat_policy,
                               grad_masks=masks)
 
     pipe = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=seq, global_batch=batch)
@@ -97,6 +107,8 @@ def train(arch: str, *, steps=100, batch=8, seq=256, reduce=True,
         params, opt_state, metrics = step_fn(params, opt_state, batch_np)
         straggler = wd.stop()
         losses.append(float(metrics["loss"]))
+        if on_step is not None:
+            on_step(step, {k: float(v) for k, v in metrics.items()})
         if step % log_every == 0 or step == steps - 1:
             print(f"step {step}: loss={losses[-1]:.4f} "
                   f"lr={float(metrics['lr']):.2e} "
@@ -127,6 +139,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    backend.enable_compile_cache()
     losses = train(args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
                    reduce=args.reduce, mode=args.mode, ckpt_dir=args.ckpt_dir,
                    ckpt_every=args.ckpt_every, lr=args.lr,

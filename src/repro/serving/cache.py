@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import lm
+from repro.parallel.sharding import place
 
 __all__ = ["SlotPool"]
 
@@ -47,7 +48,10 @@ class SlotPool:
     def __init__(self, cfg, pc, n_slots: int, max_len: int, dtype=jnp.bfloat16):
         self.n_slots = n_slots
         self.max_len = max_len
-        self.caches = lm.init_caches(cfg, pc, n_slots, max_len, dtype)
+        # placed with the model's cache shardings at construction, so reset
+        # and the engine step see one sharding and trace once
+        self.caches = place(lm.init_caches(cfg, pc, n_slots, max_len, dtype),
+                            pc.mesh, lm.cache_specs(cfg, pc))
         # donation keeps the pool at one cache's footprint on real devices;
         # CPU has no donation support and would only log noise
         donate = () if jax.default_backend() == "cpu" else (0,)

@@ -53,9 +53,10 @@ prices the wire — so AG flows keep the deterministic f32 tie-break.
 ``alpha`` and ``beta`` are the calibratable constants of the classic
 alpha-beta model: defaults below, env overrides ``REPRO_TUNE_ALPHA`` /
 ``REPRO_TUNE_BETA`` (seconds) for calibration against a real TPU.  Hardware
-constants come from ``launch.roofline.HW`` (TPU v5e) and the
-``repro.backend`` MXU probe — the model ranks relative candidates, so
-absolute calibration is not critical.
+constants come from ``repro.backend.chip()`` (the chip the program runs
+on; TPU v5e on the emulated target) and the ``repro.backend`` MXU probe —
+the model ranks relative candidates, so absolute calibration is not
+critical.
 """
 from __future__ import annotations
 
@@ -65,9 +66,9 @@ from typing import Dict, Tuple
 
 import jax.numpy as jnp
 
+from repro import backend
 from repro.core import schedules
 from repro.core.comp_tiles import DEFAULT_TILE, largest_divisor, resolve_tile, tile_footprint_bytes
-from repro.launch.roofline import HW
 from repro.tune.candidates import (
     Candidate,
     GEMM_TILE_KINDS,
@@ -258,8 +259,6 @@ def _spill_bytes(tm: int, tn: int, tk: int, acc_bytes: int) -> float:
     attention/MoE tile beat the whole-chunk native blocking on shapes whose
     chunk no longer fits.
     """
-    from repro import backend
-
     if tile_footprint_bytes((tm, tn, tk), _TILE_BYTES, acc_bytes) <= backend.vmem_budget_bytes():
         return 0.0
     return 2.0 * tm * tn * acc_bytes
@@ -281,9 +280,7 @@ def comp_step_time(kind: str, sig: Tuple[int, ...], world: int, cand: Candidate)
     nch = max(1, cand.num_channels)
     dims = _tile_dims(kind, sig, world, nch)
     if dims is None:
-        return flops / HW["peak_flops"]
-
-    from repro import backend
+        return flops / backend.chip().peak_flops
 
     m, n, k = dims
     tm, tn, tk = realized_tile(kind, sig, world, cand)
@@ -300,8 +297,8 @@ def comp_step_time(kind: str, sig: Tuple[int, ...], world: int, cand: Candidate)
         # compute term (it already prices the wire for travelling partials)
         bytes_touched = (n_tiles * (tm * tk + tk * tn) + blocks_mn * tm * tn) * _TILE_BYTES
         bytes_touched += blocks_mn * _spill_bytes(tm, tn, tk, 4)
-        t_flops = flops / (HW["peak_flops"] * eff)
-        t_mem = bytes_touched / HW["hbm_bw"]
+        t_flops = flops / (backend.chip().peak_flops * eff)
+        t_mem = bytes_touched / backend.chip().hbm_bw
         return max(t_flops, t_mem) + BETA_TILE_S * n_tiles
 
     if kind == "ag_attention":
@@ -312,16 +309,16 @@ def comp_step_time(kind: str, sig: Tuple[int, ...], world: int, cand: Candidate)
         blocks = b * h * (m // tm) * (k // tk) * nch
         n_tiles = blocks * max(1, n // tn)
         eff = (min(tm, mxu) / mxu) * (min(tk, mxu) / mxu)  # QK^T -> (tm, tk)
-        t_flops = flops / (HW["peak_flops"] * eff)
+        t_flops = flops / (backend.chip().peak_flops * eff)
         # softmax is VPU work over every score element, fp32 regardless of
         # the wire dtype (the compute term must stay accum-dtype-free)
         scores = float(b) * h * m * k * nch
-        t_soft = _SOFTMAX_OPS * scores / (HW["peak_flops"] * _VPU_FRACTION)
+        t_soft = _SOFTMAX_OPS * scores / (backend.chip().peak_flops * _VPU_FRACTION)
         # per block: Q tile + K and V tiles in, one accumulator update out;
         # a whole-chunk score tile that cannot stay resident spills fp32
         bytes_touched = blocks * (2.0 * tm * n + 2.0 * tk * n) * _TILE_BYTES
         bytes_touched += blocks * _spill_bytes(tm, tk, n, _SCORE_BYTES)
-        t_mem = bytes_touched / HW["hbm_bw"]
+        t_mem = bytes_touched / backend.chip().hbm_bw
         return max(t_flops + t_soft, t_mem) + BETA_TILE_S * n_tiles
 
     # ag_moe / a2a_dispatch: per-expert grouped GEMMs over capacity-sized
@@ -340,10 +337,10 @@ def comp_step_time(kind: str, sig: Tuple[int, ...], world: int, cand: Candidate)
     blocks = e_loc * nch * row_tiles * max(1, n // tn)
     n_tiles = blocks * max(1, k // tk) * 2  # gate+up AND down projections
     eff = (min(tm_e, mxu) / mxu) * (min(tn, mxu) / mxu) * occupancy
-    t_flops = flops / (HW["peak_flops"] * eff)
+    t_flops = flops / (backend.chip().peak_flops * eff)
     bytes_touched = (n_tiles * (tm_e * tk + tk * tn) + blocks * tm_e * tn) * _TILE_BYTES
     bytes_touched += blocks * _spill_bytes(tm_e, tn, tk, 4)
-    t_mem = bytes_touched / HW["hbm_bw"]
+    t_mem = bytes_touched / backend.chip().hbm_bw
     return max(t_flops, t_mem) + BETA_TILE_S * n_tiles
 
 
@@ -356,7 +353,7 @@ def predict_cost(kind: str, sig: Tuple[int, ...], world: int, cand: Candidate) -
     dirs = 2.0 if (cand.order == "bidir_ring" and cand.num_channels >= 2) else 1.0
     hops = _order_hops(cand.order, world)
 
-    t_comm = wire * hops / (HW["link_bw"] * dirs)
+    t_comm = wire * hops / (backend.chip().link_bw * dirs)
     t_comp = comp_step_time(kind, sig, world, cand)
 
     steady = (steps - 1) * max(t_comm, t_comp)
@@ -371,7 +368,7 @@ def _fill_drain_time(kind: str, sig: Tuple[int, ...], world: int, cand: Candidat
     wire, _ = step_terms(kind, sig, world, cand.accum_dtype, cand.flow)
     dirs = 2.0 if (cand.order == "bidir_ring" and cand.num_channels >= 2) else 1.0
     hops = _order_hops(cand.order, world)
-    t_comm = wire * hops / (HW["link_bw"] * dirs)
+    t_comm = wire * hops / (backend.chip().link_bw * dirs)
     t_comp = comp_step_time(kind, sig, world, cand)
     return (t_comm + t_comp) / cand.num_channels
 

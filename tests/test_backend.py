@@ -38,16 +38,21 @@ def emulated_target(monkeypatch):
 
 def test_describe_reports_probes():
     info = backend.describe()
-    assert info["jax_version"] == jax.__version__
-    assert info["compiler_params_cls"] in ("CompilerParams", "TPUCompilerParams")
+    assert info["jax"] == jax.__version__
+    assert info["platform"] == jax.devices()[0].platform
+    assert info["device_count"] == jax.device_count()
+    assert info["target"] == backend.target()
 
 
 def test_compiler_params_drops_unknown_fields():
-    # must not raise even for hints this JAX doesn't know
+    # a misspelled field raises instead of being dropped in silence
     params = backend.compiler_params(
-        dimension_semantics=("parallel",), not_a_real_field_ever=1
+        dimension_semantics=("parallel",), vmem_limit_bytes=32 * 2**20
     )
     assert params.dimension_semantics == ("parallel",)
+    assert params.vmem_limit_bytes == 32 * 2**20
+    with pytest.raises(TypeError):
+        backend.compiler_params(not_a_real_field_ever=1)
 
 
 def test_target_env_override(monkeypatch):
@@ -66,6 +71,52 @@ def test_resolve_interpret_emulated_forces_interpret(monkeypatch):
     # even an explicit compile request cannot compile without a TPU toolchain
     assert backend.resolve_interpret(False) is not False
     assert backend.default_interpret() is True
+
+
+def test_chip_table_refuses_unknown_kind(monkeypatch):
+    with pytest.raises(ValueError, match="unknown TPU device_kind"):
+        backend.chip("TPU v99")
+    # the tpu target reads the live device's kind: a CPU is not in the table
+    monkeypatch.setenv("REPRO_BACKEND", "tpu")
+    with pytest.raises(ValueError, match="unknown TPU device_kind"):
+        backend.vmem_budget_bytes()
+    # the emulated target models one named chip
+    monkeypatch.setenv("REPRO_BACKEND", "emulated")
+    assert backend.device_kind() == "TPU v5 lite"
+    assert backend.vmem_budget_bytes() == 16 * 2**20
+    assert backend.chip().vmem_bytes == 128 * 2**20
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    from repro.launch import roofline
+
+    assert roofline.HW["TPU v5 lite"].peak_flops == 197e12
+    terms = roofline.roofline_terms({"flops": 197e12, "bytes accessed": 819e9}, 50e9,
+                                    kind="TPU v5 lite")
+    assert terms["compute_s"] == terms["memory_s"] == terms["collective_s"] == 1.0
+    with pytest.raises(ValueError, match="unknown TPU device_kind"):
+        roofline.roofline_terms({}, 0.0, kind="TPU v99")
+
+
+def test_vmem_limit_only_above_the_default_scope():
+    assert backend.vmem_array_bytes((512, 960), jnp.float32) == 512 * 1024 * 4
+    assert backend.vmem_array_bytes((4, 9, 130), jnp.bfloat16) == 4 * 16 * 256 * 2
+    assert backend.vmem_limit_bytes(8 * 2**20) is None
+    assert backend.vmem_limit_bytes(20 * 2**20) == 25 * 2**20
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    from repro.backend import compile_cache
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert backend.enable_compile_cache() == str(tmp_path)
+    assert calls == []  # JAX reads the variable itself; no other directory
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = backend.enable_compile_cache()
+    assert calls == [("jax_compilation_cache_dir", path)]
+    assert path == str(compile_cache.CACHE_DIR) and path.endswith(".jax_cache")
 
 
 # ---- every public kernel vs. its oracle under the emulated target ------------

@@ -7,6 +7,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import kernels
 from repro.compat import shard_map, make_mesh
+from repro.core.channels import BlockChannel
 from repro.kernels import ref
 from utils import allclose
 
@@ -158,6 +159,41 @@ def test_gemm_rs_fused_ring():
         out_specs=P("model", None))
     y = jax.jit(fn)(x, w)
     allclose(y, x @ w, atol=1e-3, rtol=1e-3)
+
+
+@pytest.mark.parametrize("kind", ["ag_matmul", "matmul_rs"])
+def test_fused_kernels_pad_lane_dims(kind):
+    # K and N off the 128 lane grid: the kernels pad to it and drop the pad
+    from repro.core.compiler import compile_overlap
+
+    mesh = make_mesh((4,), ("model",))
+    fn = compile_overlap(kind, BlockChannel(axis="model"), backend="pallas", world_size=4)
+    if kind == "ag_matmul":
+        x = jax.random.normal(KEY, (4 * 16, 40), jnp.float32)
+        w = jax.random.normal(jax.random.PRNGKey(13), (40, 4 * 96), jnp.float32)
+        specs = dict(in_specs=(P("model", None), P(None, "model")), out_specs=P(None, "model"))
+    else:
+        x = jax.random.normal(KEY, (4 * 16, 4 * 40), jnp.float32)
+        w = jax.random.normal(jax.random.PRNGKey(13), (4 * 40, 96), jnp.float32)
+        specs = dict(in_specs=(P(None, "model"), P("model", None)), out_specs=P("model", None))
+    y = jax.jit(shard_map(fn, mesh, **specs))(x, w)
+    assert y.shape == (x.shape[0], w.shape[1])
+    allclose(y, x @ w, atol=1e-3, rtol=1e-3)
+
+
+def test_gemm_rs_untileable_channel_width_raises():
+    # N=384 in 2 channels gives 192-column channels: no 128-multiple block
+    # divides them, so the kernel refuses instead of emitting a block the
+    # TPU compiler would reject
+    mesh = make_mesh((4,), ("model",))
+    fn = shard_map(
+        lambda a, b: kernels.gemm_rs_shard(
+            a, b, channel=BlockChannel(axis="model", num_channels=2), world_size=4),
+        mesh, in_specs=(P(None, "model"), P("model", None)), out_specs=P("model", None))
+    x = jnp.ones((64, 4 * 32), jnp.float32)
+    w = jnp.ones((4 * 32, 384), jnp.float32)
+    with pytest.raises(NotImplementedError, match="N=384 with num_channels=2"):
+        jax.jit(fn)(x, w)
 
 
 def test_gemm_rs_matches_paper_schedule():
