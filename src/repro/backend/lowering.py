@@ -56,10 +56,12 @@ def compiler_params(*, dimension_semantics=None, **kw):
     return pltpu.CompilerParams(**kw)
 
 
-def pallas_call(kernel, *, dimension_semantics=None, interpret=None,
+def pallas_call(kernel, *, name, dimension_semantics=None, interpret=None,
                 compiler_params_kw=None, **kw):
     """``pl.pallas_call`` with TPU compiler params and the target's interpret mode.
 
+    ``name``: the kernel's stable name, which its ops carry in the compiled
+    program and the profiler's trace.
     ``interpret``: True/False, or None for "whatever the target needs"
     (the emulated target always interprets).
     """
@@ -68,6 +70,7 @@ def pallas_call(kernel, *, dimension_semantics=None, interpret=None,
     )
     return pl.pallas_call(
         kernel,
+        name=name,
         compiler_params=params,
         interpret=_resolve_interpret(interpret),
         **kw,

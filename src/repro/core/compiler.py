@@ -66,6 +66,8 @@ import functools
 import warnings
 from typing import Callable, Optional, Tuple, Union
 
+import jax
+
 from repro.core.channels import BlockChannel, CompSpec
 from repro.core.quant import QuantSpec
 from repro.core import overlap as _xla
@@ -78,6 +80,7 @@ __all__ = [
     "BACKENDS",
     "PALLAS_KINDS",
     "unsupported_error",
+    "scope_name",
 ]
 
 KINDS = ("ag_matmul", "matmul_rs", "ag_attention", "ag_moe")
@@ -89,6 +92,25 @@ PALLAS_KINDS = ("ag_matmul", "matmul_rs")
 # layer seam and the expert-parallel MoE dispatch/combine pair
 SEQ_KINDS = (("matmul_rs", "ag_matmul"), ("a2a_dispatch", "combine_rs"))
 A2A_SEQ = ("a2a_dispatch", "combine_rs")
+
+
+def scope_name(kinds) -> str:
+    """The device scope an op's lowering runs under: ``overlap.<kind>``, or
+    the kinds of a fused sequence joined by ``-``."""
+    return "overlap." + (kinds if isinstance(kinds, str) else "-".join(kinds))
+
+
+def _scoped(fn: Callable, kinds) -> Callable:
+    """``fn`` traced under :func:`scope_name`, so that its ops keep one
+    stable name in the compiled program whatever lowering was chosen."""
+    name = scope_name(kinds)
+
+    @functools.wraps(fn)
+    def run(*args, **kw):
+        with jax.named_scope(name):
+            return fn(*args, **kw)
+
+    return run
 
 
 def unsupported_error(kind: str, backend: str) -> NotImplementedError:
@@ -262,6 +284,7 @@ def compile_overlap(
                 ("ag_attention", False): _xla.ag_attention_baseline,
             }
             fn = table[(kind, overlapped)]
+        fn = _scoped(fn, kind)
         if overlapped:
             # every overlapped kind lowers kind -> plan -> generic executor;
             # the plan itself is built (and cached) at trace time, once the
@@ -280,7 +303,8 @@ def compile_overlap(
         raise unsupported_error(kind, backend)
     # interpret=None flows through to backend.resolve_interpret inside the
     # kernel's pallas_call — the target policy lives in one place only
-    return functools.partial(table[kind], channel=channel, interpret=interpret, **kw)
+    return functools.partial(_scoped(table[kind], kind), channel=channel,
+                             interpret=interpret, **kw)
 
 
 class SeamFallbackWarning(UserWarning):
@@ -494,7 +518,7 @@ def _compile_seq(
             return _seq_unfused(ch_rs, ch_ag, overlapped=True, **kw)(
                 x, w1, w2, residual=residual, glue=glue, **call_kw
             )
-        return _xla.matmul_rs_ag(
+        return _scoped(_xla.matmul_rs_ag, kinds)(
             x, w1, w2,
             axis=ch_rs.axis, channel=ch_rs, channel2=ch_ag,
             residual=residual, glue=glue, **kw, **call_kw,
@@ -555,13 +579,14 @@ def _compile_a2a(
         ch_d, ch_c = ch_d.with_(quant=quant), ch_c.with_(quant=quant)
     if not overlapped:
         return functools.partial(
-            moe_overlap.a2a_moe_baseline,
+            _scoped(moe_overlap.a2a_moe_baseline, A2A_SEQ),
             axis=ch_d.axis,
             num_channels=ch_d.num_channels,
             **kw,
         )
     return functools.partial(
-        moe_overlap.a2a_moe, axis=ch_d.axis, channel=ch_d, channel2=ch_c, **kw
+        _scoped(moe_overlap.a2a_moe, A2A_SEQ), axis=ch_d.axis, channel=ch_d,
+        channel2=ch_c, **kw
     )
 
 
@@ -619,7 +644,7 @@ def _auto_overlap_a2a(
                 num_channels=ch_d.num_channels,
                 **kw,
             )
-        return fn(x, topk_ids, topk_w, w_gu, w_down, **call_kw)
+        return _scoped(fn, A2A_SEQ)(x, topk_ids, topk_w, w_gu, w_down, **call_kw)
 
     return auto_fn
 

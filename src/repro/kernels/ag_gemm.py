@@ -259,6 +259,7 @@ def ag_gemm_shard(
         backend.vmem_array_bytes(*b) for b in blocks)
     out = backend.pallas_call(
         kern,
+        name="ag_gemm",
         grid=(world_size, nch, n_tiles),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(memory_space=backend.HBM),

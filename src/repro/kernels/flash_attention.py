@@ -143,6 +143,7 @@ def flash_attention(
     )
     return backend.pallas_call(
         kern,
+        name="flash_attention",
         grid=(bh, sq // bq, n_kv),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),

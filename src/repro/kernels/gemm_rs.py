@@ -315,6 +315,7 @@ def gemm_rs_shard(
         backend.vmem_array_bytes(*b) for b in blocks)
     out = backend.pallas_call(
         kern,
+        name="gemm_rs",
         grid=(world_size, nch, n_tiles),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(memory_space=backend.HBM),

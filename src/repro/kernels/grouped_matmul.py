@@ -72,6 +72,7 @@ def grouped_matmul(x, w, tile_expert, *, tile=(128, 128, 128), out_dtype=None, i
 
     return backend.pallas_call(
         _kernel,
+        name="grouped_matmul",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),

@@ -127,6 +127,7 @@ def ssd_intra_chunk(cum, cb, xdt, *, interpret=False):
     p = xdt.shape[-1]
     return backend.pallas_call(
         functools.partial(_ssd_intra_kernel, q=q),
+        name="mamba_ssd",
         grid=(t,),
         in_specs=[
             pl.BlockSpec((1, q, 1), lambda i: (i, 0, 0)),

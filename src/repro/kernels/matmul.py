@@ -44,6 +44,7 @@ def matmul(x, w, *, tile=DEFAULT_TILE, out_dtype=None, interpret=False):
 
     return backend.pallas_call(
         functools.partial(_matmul_kernel, n_k=n_k),
+        name="matmul",
         grid=(m // bm, n // bn, n_k),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

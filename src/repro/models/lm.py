@@ -40,6 +40,11 @@ class LayerDef:
     theta: float
     shared: bool = False  # parameters shared across occurrences (zamba2)
 
+    @property
+    def mixer_scope(self) -> str:
+        """Name of the device scope around this layer's mixer."""
+        return "mamba" if self.kind == "mamba" else "attn"
+
     # ---- params ---------------------------------------------------------------
     def init(self, key, cfg, pc, dtype):
         ks = jax.random.split(key, 2)
@@ -82,42 +87,45 @@ class LayerDef:
         mixer_params = shared_params if self.shared else params["mixer"]
         aux = jnp.zeros((), jnp.float32)
 
-        if self.kind == "mamba":
-            full = mamba.specs(cfg, pc.tp, pc.dp_spec())
-            sp = {k: pc.manual(v) for k, v in full.items()}
-            x = pc.smap(
-                lambda p_, x_: mamba.apply_seq(p_, x_, pc, cfg),
-                in_specs=(sp, P(None, "model", None)),
-                out_specs=P(None, "model", None),
-            )(pc.use_gather(mixer_params, full), x)
-        else:
-            full = attention.specs(cfg, pc.tp, pc.dp_spec())
-            sp = {k: pc.manual(v) for k, v in full.items()}
-            x = pc.smap(
-                lambda p_, x_: attention.apply_seq(
-                    p_, x_, pc, cfg, causal=True, window=self.window,
-                    rope_theta=self.theta),
-                in_specs=(sp, P(None, "model", None)),
-                out_specs=P(None, "model", None),
-            )(pc.use_gather(mixer_params, full), x)
+        with jax.named_scope(self.mixer_scope):
+            if self.kind == "mamba":
+                full = mamba.specs(cfg, pc.tp, pc.dp_spec())
+                sp = {k: pc.manual(v) for k, v in full.items()}
+                x = pc.smap(
+                    lambda p_, x_: mamba.apply_seq(p_, x_, pc, cfg),
+                    in_specs=(sp, P(None, "model", None)),
+                    out_specs=P(None, "model", None),
+                )(pc.use_gather(mixer_params, full), x)
+            else:
+                full = attention.specs(cfg, pc.tp, pc.dp_spec())
+                sp = {k: pc.manual(v) for k, v in full.items()}
+                x = pc.smap(
+                    lambda p_, x_: attention.apply_seq(
+                        p_, x_, pc, cfg, causal=True, window=self.window,
+                        rope_theta=self.theta),
+                    in_specs=(sp, P(None, "model", None)),
+                    out_specs=P(None, "model", None),
+                )(pc.use_gather(mixer_params, full), x)
 
         if self.ffn_kind == "mlp":
             full = ffn.specs(cfg, pc.tp, pc.dp_spec())
             sp = {k: pc.manual(v) for k, v in full.items()}
-            x = pc.smap(
-                lambda p_, x_: ffn.apply_seq(p_, x_, pc, cfg),
-                in_specs=(sp, P(None, "model", None)),
-                out_specs=P(None, "model", None),
-            )(pc.use_gather(params["ffn"], full), x)
+            with jax.named_scope("mlp"):
+                x = pc.smap(
+                    lambda p_, x_: ffn.apply_seq(p_, x_, pc, cfg),
+                    in_specs=(sp, P(None, "model", None)),
+                    out_specs=P(None, "model", None),
+                )(pc.use_gather(params["ffn"], full), x)
         elif self.ffn_kind == "moe":
             full = moe.specs(cfg, pc.tp, pc.dp_spec())
             sp = jax.tree_util.tree_map(
                 pc.manual, full, is_leaf=lambda v: isinstance(v, P))
-            x, aux = pc.smap(
-                lambda p_, x_: moe.apply_seq(p_, x_, pc, cfg),
-                in_specs=(sp, P(None, "model", None)),
-                out_specs=(P(None, "model", None), P()),
-            )(pc.use_gather(params["ffn"], full), x)
+            with jax.named_scope("moe"):
+                x, aux = pc.smap(
+                    lambda p_, x_: moe.apply_seq(p_, x_, pc, cfg),
+                    in_specs=(sp, P(None, "model", None)),
+                    out_specs=(P(None, "model", None), P()),
+                )(pc.use_gather(params["ffn"], full), x)
         return x, aux
 
     # ---- fused RS->AG seams (pc.fuse_seams) -----------------------------------
@@ -162,14 +170,16 @@ class LayerDef:
             it = iter(rest)
             qkv_ = next(it) if qkv is not None else None
             np_ = next(it) if next_mixer is not None else None
-            y, gu = attention.apply_seq(
-                mp_, x_, pc, cfg, causal=True, window=self.window,
-                rope_theta=self.theta, qkv=qkv_,
-                next_proj=ffn.seam_proj(fp_, cfg))
-            if np_ is None:
-                return ffn.apply_seq(fp_, y, pc, cfg, gu=gu)
-            return ffn.apply_seq(fp_, y, pc, cfg, gu=gu,
-                                 next_proj=attention.seam_proj(np_, cfg))
+            with jax.named_scope("attn"):
+                y, gu = attention.apply_seq(
+                    mp_, x_, pc, cfg, causal=True, window=self.window,
+                    rope_theta=self.theta, qkv=qkv_,
+                    next_proj=ffn.seam_proj(fp_, cfg))
+            with jax.named_scope("mlp"):
+                if np_ is None:
+                    return ffn.apply_seq(fp_, y, pc, cfg, gu=gu)
+                return ffn.apply_seq(fp_, y, pc, cfg, gu=gu,
+                                     next_proj=attention.seam_proj(np_, cfg))
 
         if next_mixer is not None:
             x, nqkv = pc.smap(
@@ -187,63 +197,66 @@ class LayerDef:
         mixer_params = shared_params if self.shared else params["mixer"]
         aux = jnp.zeros((), jnp.float32)
 
-        if self.kind == "mamba":
-            full = mamba.specs(cfg, pc.tp, pc.dp_spec())
-            sp = {k: pc.manual(v) for k, v in full.items()}
-            cs = {k: pc.manual(v) for k, v in mamba.cache_specs(pc.dp_spec()).items()}
-            x, cache = pc.smap(
-                lambda p_, x_: mamba.apply_seq(p_, x_, pc, cfg, return_state=True),
-                in_specs=(sp, P(None, "model", None)),
-                out_specs=(P(None, "model", None), cs),
-            )(pc.use_gather(mixer_params, full), x)
-        else:
-            full = attention.specs(cfg, pc.tp, pc.dp_spec())
-            sp = {k: pc.manual(v) for k, v in full.items()}
-            cs = {k: pc.manual(v) for k, v in
-                  attention.cache_specs(pc.dp_spec()).items()}
+        with jax.named_scope(self.mixer_scope):
+            if self.kind == "mamba":
+                full = mamba.specs(cfg, pc.tp, pc.dp_spec())
+                sp = {k: pc.manual(v) for k, v in full.items()}
+                cs = {k: pc.manual(v) for k, v in mamba.cache_specs(pc.dp_spec()).items()}
+                x, cache = pc.smap(
+                    lambda p_, x_: mamba.apply_seq(p_, x_, pc, cfg, return_state=True),
+                    in_specs=(sp, P(None, "model", None)),
+                    out_specs=(P(None, "model", None), cs),
+                )(pc.use_gather(mixer_params, full), x)
+            else:
+                full = attention.specs(cfg, pc.tp, pc.dp_spec())
+                sp = {k: pc.manual(v) for k, v in full.items()}
+                cs = {k: pc.manual(v) for k, v in
+                      attention.cache_specs(pc.dp_spec()).items()}
 
-            def fn(p_, x_):
-                y, kv = attention.apply_seq(
-                    p_, x_, pc, cfg, causal=True, window=self.window,
-                    rope_theta=self.theta, return_kv=True)
-                s_len = kv["k"].shape[2]
-                if self.window is not None and self.window < max_len:
-                    # ring-buffer layout: slot p % window holds position p
-                    w = self.window
-                    if s_len >= w:
-                        kv = {n: jnp.roll(a[:, :, s_len - w:], s_len % w, axis=2)
-                              for n, a in kv.items()}
+                def fn(p_, x_):
+                    y, kv = attention.apply_seq(
+                        p_, x_, pc, cfg, causal=True, window=self.window,
+                        rope_theta=self.theta, return_kv=True)
+                    s_len = kv["k"].shape[2]
+                    if self.window is not None and self.window < max_len:
+                        # ring-buffer layout: slot p % window holds position p
+                        w = self.window
+                        if s_len >= w:
+                            kv = {n: jnp.roll(a[:, :, s_len - w:], s_len % w, axis=2)
+                                  for n, a in kv.items()}
+                        else:
+                            kv = {n: jnp.pad(a, ((0, 0), (0, 0), (0, w - s_len), (0, 0)))
+                                  for n, a in kv.items()}
                     else:
-                        kv = {n: jnp.pad(a, ((0, 0), (0, 0), (0, w - s_len), (0, 0)))
+                        pad = max_len - s_len
+                        kv = {n: jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)))
                               for n, a in kv.items()}
-                else:
-                    pad = max_len - s_len
-                    kv = {n: jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)))
-                          for n, a in kv.items()}
-                return y, kv
+                    return y, kv
 
-            x, cache = pc.smap(
-                fn, in_specs=(sp, P(None, "model", None)),
-                out_specs=(P(None, "model", None), cs),
-            )(pc.use_gather(mixer_params, full), x)
+                x, cache = pc.smap(
+                    fn, in_specs=(sp, P(None, "model", None)),
+                    out_specs=(P(None, "model", None), cs),
+                )(pc.use_gather(mixer_params, full), x)
 
         if self.ffn_kind == "mlp":
             full = ffn.specs(cfg, pc.tp, pc.dp_spec())
             sp = {k: pc.manual(v) for k, v in full.items()}
-            x = pc.smap(
-                lambda p_, x_: ffn.apply_seq(p_, x_, pc, cfg),
-                in_specs=(sp, P(None, "model", None)),
-                out_specs=P(None, "model", None),
-            )(pc.use_gather(params["ffn"], full), x)
+            with jax.named_scope("mlp"):
+                x = pc.smap(
+                    lambda p_, x_: ffn.apply_seq(p_, x_, pc, cfg),
+                    in_specs=(sp, P(None, "model", None)),
+                    out_specs=P(None, "model", None),
+                )(pc.use_gather(params["ffn"], full), x)
         elif self.ffn_kind == "moe":
             full = moe.specs(cfg, pc.tp, pc.dp_spec())
             sp = jax.tree_util.tree_map(
                 pc.manual, full, is_leaf=lambda v: isinstance(v, P))
-            x, aux = pc.smap(
-                lambda p_, x_: moe.apply_seq(p_, x_, pc, cfg),
-                in_specs=(sp, P(None, "model", None)),
-                out_specs=(P(None, "model", None), P()),
-            )(pc.use_gather(params["ffn"], full), x)
+            with jax.named_scope("moe"):
+                x, aux = pc.smap(
+                    lambda p_, x_: moe.apply_seq(p_, x_, pc, cfg),
+                    in_specs=(sp, P(None, "model", None)),
+                    out_specs=(P(None, "model", None), P()),
+                )(pc.use_gather(params["ffn"], full), x)
         return x, aux, cache
 
     # ---- decode -----------------------------------------------------------------
@@ -266,46 +279,49 @@ class LayerDef:
         lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,))
         nv = (jnp.full((b,), c, jnp.int32) if q_valid is None
               else jnp.asarray(q_valid, jnp.int32))
-        if self.kind == "mamba":
-            full = mamba.specs(cfg, pc.tp, pc.dp_spec())
-            sp = {k: pc.manual(v) for k, v in full.items()}
-            cs = {k: pc.manual(v) for k, v in mamba.cache_specs(pc.dp_spec()).items()}
-            x, cache = pc.smap(
-                lambda p_, x_, c_, n_: mamba.apply_decode_chunk(
-                    p_, x_, c_, pc, cfg, q_valid=n_),
-                in_specs=(sp, P(None, None, None), cs, P(None)),
-                out_specs=(P(None, None, None), cs),
-            )(pc.use_gather(mixer_params, full), x, cache, nv)
-        else:
-            full = attention.specs(cfg, pc.tp, pc.dp_spec())
-            sp = {k: pc.manual(v) for k, v in full.items()}
-            cs = {k: pc.manual(v) for k, v in
-                  attention.cache_specs(pc.dp_spec()).items()}
-            x, cache = pc.smap(
-                lambda p_, x_, c_, l_, n_: attention.apply_decode(
-                    p_, x_, c_, l_, pc, cfg, window=self.window,
-                    rope_theta=self.theta, q_valid=n_),
-                in_specs=(sp, P(None, None, None), cs, P(None), P(None)),
-                out_specs=(P(None, None, None), cs),
-            )(pc.use_gather(mixer_params, full), x, cache, lens, nv)
+        with jax.named_scope(self.mixer_scope):
+            if self.kind == "mamba":
+                full = mamba.specs(cfg, pc.tp, pc.dp_spec())
+                sp = {k: pc.manual(v) for k, v in full.items()}
+                cs = {k: pc.manual(v) for k, v in mamba.cache_specs(pc.dp_spec()).items()}
+                x, cache = pc.smap(
+                    lambda p_, x_, c_, n_: mamba.apply_decode_chunk(
+                        p_, x_, c_, pc, cfg, q_valid=n_),
+                    in_specs=(sp, P(None, None, None), cs, P(None)),
+                    out_specs=(P(None, None, None), cs),
+                )(pc.use_gather(mixer_params, full), x, cache, nv)
+            else:
+                full = attention.specs(cfg, pc.tp, pc.dp_spec())
+                sp = {k: pc.manual(v) for k, v in full.items()}
+                cs = {k: pc.manual(v) for k, v in
+                      attention.cache_specs(pc.dp_spec()).items()}
+                x, cache = pc.smap(
+                    lambda p_, x_, c_, l_, n_: attention.apply_decode(
+                        p_, x_, c_, l_, pc, cfg, window=self.window,
+                        rope_theta=self.theta, q_valid=n_),
+                    in_specs=(sp, P(None, None, None), cs, P(None), P(None)),
+                    out_specs=(P(None, None, None), cs),
+                )(pc.use_gather(mixer_params, full), x, cache, lens, nv)
 
         if self.ffn_kind == "mlp":
             full = ffn.specs(cfg, pc.tp, pc.dp_spec())
             sp = {k: pc.manual(v) for k, v in full.items()}
-            x = pc.smap(
-                lambda p_, x_: ffn.apply_decode(p_, x_, pc, cfg),
-                in_specs=(sp, P(None, None, None)),
-                out_specs=P(None, None, None),
-            )(pc.use_gather(params["ffn"], full), x)
+            with jax.named_scope("mlp"):
+                x = pc.smap(
+                    lambda p_, x_: ffn.apply_decode(p_, x_, pc, cfg),
+                    in_specs=(sp, P(None, None, None)),
+                    out_specs=P(None, None, None),
+                )(pc.use_gather(params["ffn"], full), x)
         elif self.ffn_kind == "moe":
             full = moe.specs(cfg, pc.tp, pc.dp_spec())
             sp = jax.tree_util.tree_map(
                 pc.manual, full, is_leaf=lambda v: isinstance(v, P))
-            x = pc.smap(
-                lambda p_, x_: moe.apply_decode(p_, x_, pc, cfg),
-                in_specs=(sp, P(None, None, None)),
-                out_specs=P(None, None, None),
-            )(pc.use_gather(params["ffn"], full), x)
+            with jax.named_scope("moe"):
+                x = pc.smap(
+                    lambda p_, x_: moe.apply_decode(p_, x_, pc, cfg),
+                    in_specs=(sp, P(None, None, None)),
+                    out_specs=P(None, None, None),
+                )(pc.use_gather(params["ffn"], full), x)
         return x, cache
 
 
@@ -530,9 +546,10 @@ def forward(params, cfg, pc: ParallelContext, tokens, embeds=None,
     from repro.nn.layers import rms_norm
 
     prefix, unit, n_units, suffix = layer_plan(cfg)
-    x = embed_tokens(params, cfg, tokens, embeds)
-    x = jax.lax.with_sharding_constraint(
-        x, jax.sharding.NamedSharding(pc.mesh, P(pc.dp_spec(), "model", None)))
+    with jax.named_scope("embed"):
+        x = embed_tokens(params, cfg, tokens, embeds)
+        x = jax.lax.with_sharding_constraint(
+            x, jax.sharding.NamedSharding(pc.mesh, P(pc.dp_spec(), "model", None)))
 
     shared = params.get("shared_attn")
     aux_total = jnp.zeros((), jnp.float32)
@@ -565,12 +582,13 @@ def forward(params, cfg, pc: ParallelContext, tokens, embeds=None,
                       if remat_policy == "dots" else None)
             body = jax.checkpoint(unit_body, policy=policy)
 
-        if unroll:
-            for u in range(n_units):
-                up = jax.tree_util.tree_map(lambda a: a[u], params["scan"])
-                (x, aux_total), _ = body((x, aux_total), up)
-        else:
-            (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), params["scan"])
+        with jax.named_scope("layers"):
+            if unroll:
+                for u in range(n_units):
+                    up = jax.tree_util.tree_map(lambda a: a[u], params["scan"])
+                    (x, aux_total), _ = body((x, aux_total), up)
+            else:
+                (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), params["scan"])
 
     if pc.fuse_seams:
         x, aux_total = _seam_chain(suffix, params["suffix"], x, pc, cfg,
@@ -580,12 +598,14 @@ def forward(params, cfg, pc: ParallelContext, tokens, embeds=None,
             x, aux = d.apply_seq(p, x, pc, cfg, shared_params=shared)
             aux_total = aux_total + aux
 
-    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
-    head = _gathered_head(params, cfg, pc)
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-    logits = jax.lax.with_sharding_constraint(
-        logits, jax.sharding.NamedSharding(pc.mesh, P(pc.dp_spec(), None, "model")))
-    return logits[..., : cfg.vocab_size], aux_total
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_ln"], cfg.norm_eps)
+    with jax.named_scope("head"):
+        head = _gathered_head(params, cfg, pc)
+        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
+        logits = jax.lax.with_sharding_constraint(
+            logits, jax.sharding.NamedSharding(pc.mesh, P(pc.dp_spec(), None, "model")))
+        return logits[..., : cfg.vocab_size], aux_total
 
 
 def prefill(params, cfg, pc: ParallelContext, tokens, embeds=None, *,
@@ -597,9 +617,10 @@ def prefill(params, cfg, pc: ParallelContext, tokens, embeds=None, *,
     from repro.nn.layers import rms_norm
 
     prefix, unit, n_units, suffix = layer_plan(cfg)
-    x = embed_tokens(params, cfg, tokens, embeds)
-    x = jax.lax.with_sharding_constraint(
-        x, jax.sharding.NamedSharding(pc.mesh, P(pc.dp_spec(), "model", None)))
+    with jax.named_scope("embed"):
+        x = embed_tokens(params, cfg, tokens, embeds)
+        x = jax.lax.with_sharding_constraint(
+            x, jax.sharding.NamedSharding(pc.mesh, P(pc.dp_spec(), "model", None)))
     shared = params.get("shared_attn")
 
     pre_caches = []
@@ -617,28 +638,29 @@ def prefill(params, cfg, pc: ParallelContext, tokens, embeds=None, *,
                 caches.append(c)
             return h, caches
 
-        if unroll:
-            collected = []
-            for u in range(n_units):
-                up = jax.tree_util.tree_map(lambda a: a[u], params["scan"])
-                x, cs_u = unit_body(x, up)
-                collected.append(cs_u)
-            scan_caches = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *collected)
-        else:
-            x, scan_caches = jax.lax.scan(unit_body, x, params["scan"])
+        with jax.named_scope("layers"):
+            if unroll:
+                collected = []
+                for u in range(n_units):
+                    up = jax.tree_util.tree_map(lambda a: a[u], params["scan"])
+                    x, cs_u = unit_body(x, up)
+                    collected.append(cs_u)
+                scan_caches = jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs), *collected)
+            else:
+                x, scan_caches = jax.lax.scan(unit_body, x, params["scan"])
 
     suf_caches = []
     for d, p in zip(suffix, params["suffix"]):
         x, _, c = d.apply_prefill(p, x, pc, cfg, max_len, shared_params=shared)
         suf_caches.append(c)
 
-    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
-    head = _gathered_head(params, cfg, pc)
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-    return logits[..., : cfg.vocab_size], {"prefix": pre_caches,
-                                           "scan": scan_caches,
-                                           "suffix": suf_caches}
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_ln"], cfg.norm_eps)
+    with jax.named_scope("head"):
+        head = _gathered_head(params, cfg, pc)
+        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))[..., : cfg.vocab_size]
+    return logits, {"prefix": pre_caches, "scan": scan_caches, "suffix": suf_caches}
 
 
 # -----------------------------------------------------------------------------
@@ -691,7 +713,8 @@ def decode_step(params, caches, cfg, pc: ParallelContext, tokens, cache_len,
     from repro.nn.layers import rms_norm
 
     prefix, unit, n_units, suffix = layer_plan(cfg)
-    x = embed_tokens(params, cfg, tokens)
+    with jax.named_scope("embed"):
+        x = embed_tokens(params, cfg, tokens)
     shared = params.get("shared_attn")
 
     new_prefix = []
@@ -712,18 +735,19 @@ def decode_step(params, caches, cfg, pc: ParallelContext, tokens, cache_len,
                 new_caches.append(c)
             return h, new_caches
 
-        if unroll:
-            collected = []
-            for u in range(n_units):
-                up = jax.tree_util.tree_map(lambda a: a[u], params["scan"])
-                uc = jax.tree_util.tree_map(lambda a: a[u], caches["scan"])
-                x, cs_u = unit_body(x, (up, uc))
-                collected.append(cs_u)
-            new_scan = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
-                                              *collected)
-        else:
-            x, new_scan = jax.lax.scan(unit_body, x,
-                                       (params["scan"], caches["scan"]))
+        with jax.named_scope("layers"):
+            if unroll:
+                collected = []
+                for u in range(n_units):
+                    up = jax.tree_util.tree_map(lambda a: a[u], params["scan"])
+                    uc = jax.tree_util.tree_map(lambda a: a[u], caches["scan"])
+                    x, cs_u = unit_body(x, (up, uc))
+                    collected.append(cs_u)
+                new_scan = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                                  *collected)
+            else:
+                x, new_scan = jax.lax.scan(unit_body, x,
+                                           (params["scan"], caches["scan"]))
 
     new_suffix = []
     for d, p, c in zip(suffix, params["suffix"], caches["suffix"]):
@@ -731,9 +755,9 @@ def decode_step(params, caches, cfg, pc: ParallelContext, tokens, cache_len,
                               shared_params=shared, q_valid=q_valid)
         new_suffix.append(c)
 
-    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
-    head = _gathered_head(params, cfg, pc)
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-    return logits[..., : cfg.vocab_size], {"prefix": new_prefix,
-                                           "scan": new_scan,
-                                           "suffix": new_suffix}
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_ln"], cfg.norm_eps)
+    with jax.named_scope("head"):
+        head = _gathered_head(params, cfg, pc)
+        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))[..., : cfg.vocab_size]
+    return logits, {"prefix": new_prefix, "scan": new_scan, "suffix": new_suffix}

@@ -505,18 +505,21 @@ def apply_decode(params, x, cache, cache_len, pc, cfg, *, window=None,
         # buf [kv_loc, L, hd], vals [kv_loc, C, hd], idx [C]
         return buf.at[:, idx].set(vals, mode="drop")
 
-    ck = jax.vmap(_write)(cache["k"], k.transpose(0, 2, 1, 3), slots)
-    cv = jax.vmap(_write)(cache["v"], v.transpose(0, 2, 1, 3), slots)
+    with jax.named_scope("kv_write"):
+        ck = jax.vmap(_write)(cache["k"], k.transpose(0, 2, 1, 3), slots)
+        cv = jax.vmap(_write)(cache["v"], v.transpose(0, 2, 1, 3), slots)
 
     qh = q.transpose(0, 2, 1, 3)  # [b, h_loc, C, hd]
     rep = lay.h_loc // lay.kv_loc
-    kk = jnp.repeat(cache["k"], rep, axis=1) if rep > 1 else cache["k"]
-    vv = jnp.repeat(cache["v"], rep, axis=1) if rep > 1 else cache["v"]
+    with jax.named_scope("kv_read"):
+        kk = jnp.repeat(cache["k"], rep, axis=1) if rep > 1 else cache["k"]
+        vv = jnp.repeat(cache["v"], rep, axis=1) if rep > 1 else cache["v"]
+        kk, vv = kk.astype(jnp.float32), vv.astype(jnp.float32)
     kc = jnp.repeat(k, rep, axis=2) if rep > 1 else k  # [b, C, h_loc, hd]
     vc = jnp.repeat(v, rep, axis=2) if rep > 1 else v
     qf = (qh * hd ** -0.5).astype(jnp.float32)
     # part 1: the pre-existing cache rows (the chunk is not in them yet)
-    s1 = jnp.einsum("bhqd,bhkd->bhqk", qf, kk.astype(jnp.float32))
+    s1 = jnp.einsum("bhqd,bhkd->bhqk", qf, kk)
     j = jnp.arange(cache_size)
     if ring:
         # slot j last held position p_j = last - ((last - j) mod size)
@@ -541,8 +544,7 @@ def apply_decode(params, x, cache, cache_len, pc, cfg, *, window=None,
     s2 = jnp.where(m2[:, None], s2, -1e30)
 
     p = jax.nn.softmax(jnp.concatenate([s1, s2], axis=-1), axis=-1)
-    o = jnp.einsum("bhqk,bhkd->bhqd", p[..., :cache_size],
-                   vv.astype(jnp.float32))
+    o = jnp.einsum("bhqk,bhkd->bhqd", p[..., :cache_size], vv)
     o = o + jnp.einsum("bhqk,bkhd->bhqd", p[..., cache_size:],
                        vc.astype(jnp.float32))
     o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(b, c, lay.h_loc * hd)

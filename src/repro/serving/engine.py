@@ -14,6 +14,12 @@ advances every admitted sequence one iteration:
   * the host syncs exactly once per step (``jax.device_get`` of the token
     buffer), asserted by ``stats["host_syncs"] == stats["steps"]``.
 
+Each step records host spans in the profiler's trace (``repro.serve.step``
+around ``repro.serve.admit``, ``.prep``, ``.dispatch``, ``.fetch`` and
+``.post``; ``.post`` carries the step's counts as arguments), and the jitted
+step names its device work (``mixed_pass``, ``decode_pass``, ``sample``).
+``stats`` sums the same counts over steps, for a reader without a profiler.
+
 ``poll(handle)`` reads a request's progress, ``step()``'s return value is
 the streaming surface ({handle: new tokens}), and ``drain()`` runs steps to
 completion.  ``generate(prompts, max_new_tokens)`` keeps the legacy
@@ -41,6 +47,12 @@ __all__ = ["ServeEngine", "Request"]
 _TOPK_MAX = 64  # static width of the top-k threshold lattice (clamped to V)
 
 
+def _span(name):
+    """A host span in the profiler's trace (nearly free when none runs)."""
+    return jax.profiler.TraceAnnotation(f"repro.serve.{name}")
+
+
+@jax.named_scope("sample")
 def _sample(logits, temp, topk, keys):
     """Per-slot on-device sampling. logits [S, V] f32; temp/topk/keys [S...]."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -80,8 +92,11 @@ class ServeEngine:
         self.scheduler = Scheduler(self.n_slots)
         self.pool = SlotPool(cfg, pc, self.n_slots, self.max_len,
                              self.cache_dtype)
+        # sums over steps; "passes" counts the decode passes of the loop,
+        # "slots_busy" and "queued" are slot-steps and request-steps
         self.stats = {"steps": 0, "host_syncs": 0, "step_traces": 0,
-                      "resets": 0}
+                      "admitted": 0, "passes": 0, "prefill_tokens": 0,
+                      "decode_tokens": 0, "slots_busy": 0, "queued": 0}
         donate = () if jax.default_backend() == "cpu" else (1,)
         self._step_fn = jax.jit(self._build_step(), donate_argnums=donate)
         self.decode_channels = self._warm_decode_channels() if pc.tune else {}
@@ -96,8 +111,9 @@ class ServeEngine:
             self.stats["step_traces"] += 1
             n = tokens.shape[0]
             # mixed forward: prefill chunks + pending decode tokens together
-            logits, caches = lm.decode_step(params, caches, cfg, pc, tokens,
-                                            lens, q_valid=valid)
+            with jax.named_scope("mixed_pass"):
+                logits, caches = lm.decode_step(params, caches, cfg, pc, tokens,
+                                                lens, q_valid=valid)
             lens = lens + valid
             idx = jnp.clip(valid - 1, 0, tokens.shape[1] - 1)
             last = jnp.take_along_axis(
@@ -115,6 +131,7 @@ class ServeEngine:
             def cond(st):
                 return (st[0] < n_decode) & jnp.any(st[4])
 
+            @jax.named_scope("decode_pass")
             def body(st):
                 t, caches_, lens_, tok, alive_, buf_, em_, ns_ = st
                 lg, caches_ = lm.decode_step(
@@ -133,7 +150,8 @@ class ServeEngine:
             st = (jnp.int32(1), caches, lens, tok0, alive, buf, emitted,
                   n_sampled)
             st = jax.lax.while_loop(cond, body, st)
-            return st[1], st[5], st[6]
+            # buffer column 0 is the mixed pass's token: t - 1 decode passes ran
+            return st[1], st[5], st[6], st[0] - 1
 
         return step_fn
 
@@ -150,13 +168,17 @@ class ServeEngine:
         return self.scheduler.submit(req)
 
     def _admit(self) -> None:
-        for slot in self.scheduler.admit():
-            self.pool.reset(slot)
-            self.stats["resets"] += 1
+        with _span("admit") as span:
+            seated = self.scheduler.admit()
+            for slot in seated:
+                self.pool.reset(slot)
+            self.stats["admitted"] += len(seated)
+            span.set_metadata(admitted=len(seated))
 
     def _fetch(self, tree):
-        self.stats["host_syncs"] += 1
-        return jax.device_get(tree)
+        with _span("fetch"):
+            self.stats["host_syncs"] += 1
+            return jax.device_get(tree)
 
     def step(self) -> Dict[int, List[int]]:
         """Advance every admitted sequence one iteration.
@@ -164,10 +186,34 @@ class ServeEngine:
         Returns {handle: tokens emitted this step} — the streaming surface.
         Exactly one host sync regardless of how many tokens were decoded.
         """
-        self._admit()
+        with _span("step"):
+            self._admit()
+            sch = self.scheduler
+            if not any(r is not None for r in sch.slots):
+                return {}
+            with _span("prep"):
+                inputs, valid, prefill = self._prepare()
+            with _span("dispatch"):
+                out = self._step_fn(self.params, self.pool.caches,
+                                    *(jnp.asarray(a) for a in inputs))
+                self.pool.caches = out[0]
+            buf, emitted, passes = self._fetch(out[1:])
+            self.stats["steps"] += 1
+            with _span("post") as span:
+                counts = {"passes": int(passes), "prefill_tokens": prefill,
+                          "decode_tokens": int(emitted.sum()),
+                          "slots_busy": sum(r is not None for r in sch.slots)}
+                results = self._post(buf, emitted, valid)
+                counts["queued"] = len(sch.queue)
+                for k, v in counts.items():
+                    self.stats[k] += v
+                span.set_metadata(**counts)
+            return results
+
+    def _prepare(self):
+        """The step's host inputs (after params and caches), each slot's fed
+        rows, and the prompt tokens fed."""
         sch = self.scheduler
-        if not any(r is not None for r in sch.slots):
-            return {}
         n, c = self.n_slots, self.prefill_chunk
         tokens = np.zeros((n, c), np.int32)
         valid = np.zeros((n,), np.int32)
@@ -179,6 +225,7 @@ class ServeEngine:
         seeds = np.zeros((n,), np.int32)
         nsamp = np.zeros((n,), np.int32)
         lens = np.zeros((n,), np.int32)
+        prefill = 0
         for i, st in sch.active():
             req = st.request
             lens[i] = st.cache_len
@@ -192,6 +239,7 @@ class ServeEngine:
                 take = min(c, len(st.prompt) - st.pos)
                 tokens[i, :take] = st.prompt[st.pos:st.pos + take]
                 valid[i] = take
+                prefill += take
                 st.pos += take
                 active[i] = st.pos == len(st.prompt)
             else:
@@ -201,17 +249,13 @@ class ServeEngine:
         n_decode = int(min(self.decode_block,
                            max([0] + [int(budget[i]) for i, _ in sch.active()
                                       if active[i]])))
+        inputs = (lens, tokens, valid, active, budget, eos, temp, topk, seeds,
+                  nsamp, np.int32(n_decode))
+        return inputs, valid, prefill
 
-        out = self._step_fn(self.params, self.pool.caches, jnp.asarray(lens),
-                            jnp.asarray(tokens), jnp.asarray(valid),
-                            jnp.asarray(active), jnp.asarray(budget),
-                            jnp.asarray(eos), jnp.asarray(temp),
-                            jnp.asarray(topk), jnp.asarray(seeds),
-                            jnp.asarray(nsamp), jnp.int32(n_decode))
-        self.pool.caches = out[0]
-        buf, emitted = self._fetch(out[1:])
-        self.stats["steps"] += 1
-
+    def _post(self, buf, emitted, valid) -> Dict[int, List[int]]:
+        """Hand the fetched tokens to their requests; release finished slots."""
+        sch = self.scheduler
         results: Dict[int, List[int]] = {}
         finished = []
         for i, st in sch.active():

@@ -43,11 +43,14 @@ def make_train_step(model, cfg, pc, opt_cfg: AdamWConfig, *,
             ce = softmax_xent(logits, batch["labels"], batch.get("mask"))
             return ce + aux_weight * aux, (ce, aux)
 
-        (loss, (ce, aux)), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+        with jax.named_scope("loss"):
+            (loss, (ce, aux)), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
         if sync_kv and hasattr(model, "sync_grads"):
-            grads = model.sync_grads(grads, cfg, pc)
-        new_params, new_opt, om = apply_update(
-            params, grads, opt_state, opt_cfg, grad_masks=grad_masks)
+            with jax.named_scope("grad_sync"):
+                grads = model.sync_grads(grads, cfg, pc)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, om = apply_update(
+                params, grads, opt_state, opt_cfg, grad_masks=grad_masks)
         metrics = {"loss": loss, "ce": ce, "aux": aux, **om}
         return new_params, new_opt, metrics
 

@@ -156,6 +156,28 @@ def test_step_host_sync_and_trace_counts(pc8, mesh8):
         np.testing.assert_array_equal(outs[h], ref[0])
 
 
+@pytest.mark.parametrize("n_requests", [1, 3])
+def test_step_counts_decode_passes(pc8, mesh8, n_requests):
+    """A batch seated together decodes in lockstep: each step's mixed pass
+    gives every request its first token of the step, and the engine's
+    ``passes`` counts the decode passes that gave the rest."""
+    from repro.serving import Request
+
+    cfg, params = _build("smollm-360m", pc8, mesh8)
+    eng = ServeEngine(cfg, pc8, params, max_len=64, n_slots=4, decode_block=4)
+    prompt = np.arange(6, dtype=np.int32)
+    hs = [eng.submit(Request(tokens=prompt, max_new_tokens=11)) for _ in range(n_requests)]
+    firsts = 0  # steps in which the batch received tokens
+    while not all(eng.poll(h)["done"] for h in hs):
+        firsts += bool(eng.step())
+    st = eng.stats
+    assert st["passes"] == 11 - firsts > 0
+    assert st["decode_tokens"] == 11 * n_requests
+    assert st["prefill_tokens"] == len(prompt) * n_requests
+    assert st["admitted"] == n_requests
+    assert st["host_syncs"] == st["steps"]
+
+
 def test_exact_token_count_and_eos(pc8, mesh8):
     """Exactly max_new_tokens tokens unless eos arrives first; eos stops the
     slot early and is included in the output (bugfix satellite)."""
