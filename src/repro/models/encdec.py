@@ -240,6 +240,7 @@ def decode_step(params, caches, cfg, pc: ParallelContext, tokens, cache_len,
     asp = {k: pc.manual(v) for k, v in
            attention.specs(cfg, pc.tp, dp).items()}
     csp = {k: pc.manual(v) for k, v in attention.cache_specs(dp).items()}
+    rsp = {k: pc.manual(v) for k, v in attention.rows_specs(dp).items()}
     xr = P(None, None, None)
 
     afull = attention.specs(cfg, pc.tp, dp)
@@ -250,9 +251,9 @@ def decode_step(params, caches, cfg, pc: ParallelContext, tokens, cache_len,
         lp = {"attn": pc.use_gather(lp["attn"], afull),
               "cross": pc.use_gather(lp["cross"], afull),
               "ffn": pc.use_gather(lp["ffn"], ffull)}
-        h, self_c = pc.smap(
+        h, rows = pc.smap(
             lambda p_, x_, c_, n_: attention.apply_decode(p_, x_, c_, n_, pc, cfg),
-            in_specs=(asp, xr, csp, P()), out_specs=(xr, csp),
+            in_specs=(asp, xr, csp, P()), out_specs=(xr, rsp),
         )(lp["attn"], h, self_c, cache_len)
         h = pc.smap(
             lambda p_, x_, c_: attention.apply_cross_decode(p_, x_, c_, pc, cfg),
@@ -261,21 +262,21 @@ def decode_step(params, caches, cfg, pc: ParallelContext, tokens, cache_len,
         fsp = {k: pc.manual(v) for k, v in ffn.specs(cfg, pc.tp, dp).items()}
         h = pc.smap(lambda p_, x_: ffn.apply_decode(p_, x_, pc, cfg),
                     in_specs=(fsp, xr), out_specs=xr)(lp["ffn"], h)
-        return h, self_c
+        return h, rows
 
     if unroll:
-        import jax.numpy as _jnp
         collected = []
         for u in range(cfg.n_layers):
             def sl(t, _u=u):
                 return jax.tree_util.tree_map(lambda a: a[_u], t)
-            x, sc = body(x, (sl(params["dec_scan"]), sl(caches["self"]),
-                             sl(caches["cross"])))
-            collected.append(sc)
-        new_self = jax.tree_util.tree_map(lambda *xs: _jnp.stack(xs), *collected)
+            x, rows = body(x, (sl(params["dec_scan"]), sl(caches["self"]),
+                               sl(caches["cross"])))
+            collected.append(rows)
+        rows = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *collected)
     else:
-        x, new_self = jax.lax.scan(
+        x, rows = jax.lax.scan(
             body, x, (params["dec_scan"], caches["self"], caches["cross"]))
+    new_self = attention.write_rows(caches["self"], rows, pc)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps)
     head = jax.lax.with_sharding_constraint(
         params["lm_head"], jax.sharding.NamedSharding(pc.mesh, P(None, "model")))

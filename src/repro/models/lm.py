@@ -274,6 +274,10 @@ class LayerDef:
 
     def apply_decode(self, params, x, cache, cache_len, pc, cfg,
                      shared_params=None, q_valid=None):
+        """One decode chunk through this layer, reading ``cache`` only.
+
+        Returns (x, update): for attention the chunk's new cache rows, for
+        mamba the whole new state; :meth:`write_cache` applies it."""
         mixer_params = shared_params if self.shared else params["mixer"]
         b, c = x.shape[0], x.shape[1]
         lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,))
@@ -284,7 +288,7 @@ class LayerDef:
                 full = mamba.specs(cfg, pc.tp, pc.dp_spec())
                 sp = {k: pc.manual(v) for k, v in full.items()}
                 cs = {k: pc.manual(v) for k, v in mamba.cache_specs(pc.dp_spec()).items()}
-                x, cache = pc.smap(
+                x, update = pc.smap(
                     lambda p_, x_, c_, n_: mamba.apply_decode_chunk(
                         p_, x_, c_, pc, cfg, q_valid=n_),
                     in_specs=(sp, P(None, None, None), cs, P(None)),
@@ -295,14 +299,19 @@ class LayerDef:
                 sp = {k: pc.manual(v) for k, v in full.items()}
                 cs = {k: pc.manual(v) for k, v in
                       attention.cache_specs(pc.dp_spec()).items()}
-                x, cache = pc.smap(
+                rs = {k: pc.manual(v) for k, v in
+                      attention.rows_specs(pc.dp_spec()).items()}
+                x, update = pc.smap(
                     lambda p_, x_, c_, l_, n_: attention.apply_decode(
                         p_, x_, c_, l_, pc, cfg, window=self.window,
                         rope_theta=self.theta, q_valid=n_),
                     in_specs=(sp, P(None, None, None), cs, P(None), P(None)),
-                    out_specs=(P(None, None, None), cs),
+                    out_specs=(P(None, None, None), rs),
                 )(pc.use_gather(mixer_params, full), x, cache, lens, nv)
+        return self.ffn_decode(params, x, pc, cfg), update
 
+    def ffn_decode(self, params, x, pc, cfg):
+        """This layer's FFN (if any) on a decode chunk x: [B, C, D]."""
         if self.ffn_kind == "mlp":
             full = ffn.specs(cfg, pc.tp, pc.dp_spec())
             sp = {k: pc.manual(v) for k, v in full.items()}
@@ -322,7 +331,16 @@ class LayerDef:
                     in_specs=(sp, P(None, None, None)),
                     out_specs=P(None, None, None),
                 )(pc.use_gather(params["ffn"], full), x)
-        return x, cache
+        return x
+
+    def write_cache(self, cache, update, pc):
+        """This layer's cache after :meth:`apply_decode`'s ``update``, over
+        any leading layer axis: attention writes its new rows into the
+        cache; a mamba state is small and rewritten whole by its recurrence,
+        so the update is the new cache."""
+        if self.kind == "mamba":
+            return update
+        return attention.write_rows(cache, update, pc, window=self.window)
 
 
 def _layer_def(cfg, kind: str) -> LayerDef:
@@ -719,21 +737,23 @@ def decode_step(params, caches, cfg, pc: ParallelContext, tokens, cache_len,
 
     new_prefix = []
     for d, p, c in zip(prefix, params["prefix"], caches["prefix"]):
-        x, c = d.apply_decode(p, x, c, cache_len, pc, cfg,
+        x, u = d.apply_decode(p, x, c, cache_len, pc, cfg,
                               shared_params=shared, q_valid=q_valid)
-        new_prefix.append(c)
+        new_prefix.append(d.write_cache(c, u, pc))
 
     new_scan = caches.get("scan")
     if n_units:
+        # the scan reads the stacked caches and returns only each layer's
+        # update; one write after it puts them in, in place
         def unit_body(h, xs):
             unit_params, unit_caches = xs
-            new_caches = []
+            updates = []
             for i, d in enumerate(unit):
-                h, c = d.apply_decode(unit_params[i], h, unit_caches[i],
+                h, u = d.apply_decode(unit_params[i], h, unit_caches[i],
                                       cache_len, pc, cfg, shared_params=shared,
                                       q_valid=q_valid)
-                new_caches.append(c)
-            return h, new_caches
+                updates.append(u)
+            return h, updates
 
         with jax.named_scope("layers"):
             if unroll:
@@ -741,19 +761,21 @@ def decode_step(params, caches, cfg, pc: ParallelContext, tokens, cache_len,
                 for u in range(n_units):
                     up = jax.tree_util.tree_map(lambda a: a[u], params["scan"])
                     uc = jax.tree_util.tree_map(lambda a: a[u], caches["scan"])
-                    x, cs_u = unit_body(x, (up, uc))
-                    collected.append(cs_u)
-                new_scan = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
-                                                  *collected)
+                    x, us = unit_body(x, (up, uc))
+                    collected.append(us)
+                updates = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                                 *collected)
             else:
-                x, new_scan = jax.lax.scan(unit_body, x,
-                                           (params["scan"], caches["scan"]))
+                x, updates = jax.lax.scan(unit_body, x,
+                                          (params["scan"], caches["scan"]))
+            new_scan = [d.write_cache(c, u, pc)
+                        for d, c, u in zip(unit, caches["scan"], updates)]
 
     new_suffix = []
     for d, p, c in zip(suffix, params["suffix"], caches["suffix"]):
-        x, c = d.apply_decode(p, x, c, cache_len, pc, cfg,
+        x, u = d.apply_decode(p, x, c, cache_len, pc, cfg,
                               shared_params=shared, q_valid=q_valid)
-        new_suffix.append(c)
+        new_suffix.append(d.write_cache(c, u, pc))
 
     with jax.named_scope("final_norm"):
         x = rms_norm(x, params["final_ln"], cfg.norm_eps)

@@ -19,6 +19,7 @@ grad-masked so semantics match the unpadded architecture exactly.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional
 
 import jax
@@ -26,14 +27,20 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro.backend import shard_map
 from repro.nn.layers import (
     rms_norm, rope, he_init, gqa_layout, GQALayout,
 )
 
 __all__ = [
     "init", "specs", "grad_masks", "apply_seq", "apply_seq_ring", "apply_decode",
-    "init_cache", "chunked_attention", "seam_proj",
+    "init_cache", "chunked_attention", "seam_proj", "write_rows",
 ]
+
+
+# the lanes of a TPU tile, along ``max_len``: write_rows reads and writes
+# back spans of cache rows that start at a multiple of this
+_SPAN = 128
 
 
 def _lay(cfg, tp) -> GQALayout:
@@ -461,14 +468,20 @@ def apply_decode(params, x, cache, cache_len, pc, cfg, *, window=None,
     ``cache_len`` is the number of tokens already in each slot's cache — a
     scalar or a per-slot [B] vector (the continuous-batching engine runs
     heterogeneous lengths).  ``q_valid`` ([B] int, optional) is how many of
-    the C chunk rows are real per slot: rows past it write nothing (the
-    scatter index goes out of bounds and is dropped) and their outputs are
-    garbage the caller ignores.  Returns (x_out, new_cache).
+    the C chunk rows are real per slot: rows past it leave the cache as it
+    is and their outputs are garbage the caller ignores.
+
+    The cache is only read.  Returns (x_out, rows): the chunk's new keys and
+    values ``rows["k"]``/``["v"]`` [B, kv_loc, C, hd] and ``rows["slot"]``
+    [B, C], the cache row each goes to (``cache_size``, out of bounds, past
+    ``q_valid``).  :func:`write_rows` puts them in, so a caller holding many
+    layers' caches writes them all at once.
 
     The chunk attends in two parts — the pre-existing cache rows, then the
     causal in-chunk keys — so the chunk's own k/v never round-trip through a
-    ring slot another in-flight query still needs.  Requires C <= cache size
-    for ring (sliding-window) layers.
+    ring slot another in-flight query still needs.  Query head ``g*rep + r``
+    reads KV head ``g`` where it lies, with no expanded copy of the cache.
+    Requires C <= cache size for ring (sliding-window) layers.
     """
     lay = _lay(cfg, pc.tp)
     hd = cfg.hd
@@ -491,35 +504,39 @@ def apply_decode(params, x, cache, cache_len, pc, cfg, *, window=None,
     q, k = rope(q, k, pos, theta)
 
     cache_size = cache["k"].shape[2]
-    ring = window is not None and cache_size <= window
+    ring = is_ring(cache_size, window)
     if ring and c > cache_size:
         raise ValueError(
             f"decode chunk C={c} exceeds ring cache size {cache_size}; "
             "chunked prefill must keep chunks within the sliding window")
-    # per-(slot, row) scatter: invalid rows target slot ``cache_size``,
-    # which is out of bounds and dropped by mode="drop"
+    # each row's target: rows past q_valid target ``cache_size``, out of
+    # bounds, which write_rows drops
     slots = jnp.remainder(pos, cache_size) if ring else pos
     slots = jnp.where(jnp.arange(c)[None, :] < nv[:, None], slots, cache_size)
+    rows = {"k": k.transpose(0, 2, 1, 3), "v": v.transpose(0, 2, 1, 3),
+            "slot": slots}
 
-    def _write(buf, vals, idx):
-        # buf [kv_loc, L, hd], vals [kv_loc, C, hd], idx [C]
-        return buf.at[:, idx].set(vals, mode="drop")
-
-    with jax.named_scope("kv_write"):
-        ck = jax.vmap(_write)(cache["k"], k.transpose(0, 2, 1, 3), slots)
-        cv = jax.vmap(_write)(cache["v"], v.transpose(0, 2, 1, 3), slots)
-
+    g, rep = lay.kv_loc, lay.h_loc // lay.kv_loc
     qh = q.transpose(0, 2, 1, 3)  # [b, h_loc, C, hd]
-    rep = lay.h_loc // lay.kv_loc
+    qf = (qh * hd ** -0.5).astype(jnp.float32).reshape(b, g, rep, c, hd)
+    # part 1: the pre-existing cache rows (the chunk is not in them yet).
+    # One query row per head is a multiply and sum in f32 that reads the
+    # cache where it lies; as a product on the MXU, XLA would convert the
+    # whole stacked cache to bf16 ahead of the layer scan on every pass.
+    one_row = c == 1
+    # the cache as the products take it; for one row, broadcast here (in
+    # the fused product, not in memory) so that XLA's staging of each layer's
+    # K and V, which it names after the broadcast, is named as a cache read
     with jax.named_scope("kv_read"):
-        kk = jnp.repeat(cache["k"], rep, axis=1) if rep > 1 else cache["k"]
-        vv = jnp.repeat(cache["v"], rep, axis=1) if rep > 1 else cache["v"]
-        kk, vv = kk.astype(jnp.float32), vv.astype(jnp.float32)
-    kc = jnp.repeat(k, rep, axis=2) if rep > 1 else k  # [b, C, h_loc, hd]
-    vc = jnp.repeat(v, rep, axis=2) if rep > 1 else v
-    qf = (qh * hd ** -0.5).astype(jnp.float32)
-    # part 1: the pre-existing cache rows (the chunk is not in them yet)
-    s1 = jnp.einsum("bhqd,bhkd->bhqk", qf, kk)
+        kk = cache["k"].astype(jnp.float32)  # [b, g, L, hd]
+        vv = cache["v"].astype(jnp.float32)
+        if one_row:  # against [b, g, rep, 1, (L,) hd]
+            kk, vv = (jnp.broadcast_to(a[:, :, None, None], (b, g, rep, 1) + a.shape[2:])
+                      for a in (kk, vv))
+    if one_row:
+        s1 = jnp.sum(qf[..., None, :] * kk, axis=-1)
+    else:
+        s1 = jnp.einsum("bgrqd,bgkd->bgrqk", qf, kk)
     j = jnp.arange(cache_size)
     if ring:
         # slot j last held position p_j = last - ((last - j) mod size)
@@ -533,20 +550,107 @@ def apply_decode(params, x, cache, cache_len, pc, cfg, *, window=None,
                               (b, c, cache_size))
         if window is not None:
             m1 = m1 & ((pos[:, :, None] - j[None, None, :]) < window)
-    s1 = jnp.where(m1[:, None], s1, -1e30)
+    s1 = jnp.where(m1[:, None, None], s1, -1e30)
     # part 2: causal in-chunk keys (row i attends rows <= i, valid only)
-    s2 = jnp.einsum("bhqd,bkhd->bhqk", qf, kc.astype(jnp.float32))
+    s2 = jnp.einsum("bgrqd,bkgd->bgrqk", qf, k.astype(jnp.float32))
     qi = jnp.arange(c)
     m2 = (qi[None, :, None] >= qi[None, None, :]) & \
         (qi[None, None, :] < nv[:, None, None])  # [B, C, C]
     if window is not None:
         m2 = m2 & ((qi[None, :, None] - qi[None, None, :]) < window)
-    s2 = jnp.where(m2[:, None], s2, -1e30)
+    s2 = jnp.where(m2[:, None, None], s2, -1e30)
 
     p = jax.nn.softmax(jnp.concatenate([s1, s2], axis=-1), axis=-1)
-    o = jnp.einsum("bhqk,bhkd->bhqd", p[..., :cache_size], vv)
-    o = o + jnp.einsum("bhqk,bkhd->bhqd", p[..., cache_size:],
-                       vc.astype(jnp.float32))
-    o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(b, c, lay.h_loc * hd)
+    if one_row:
+        o = jnp.sum(p[..., :cache_size, None] * vv, axis=-2)
+    else:
+        o = jnp.einsum("bgrqk,bgkd->bgrqd", p[..., :cache_size], vv)
+    o = o + jnp.einsum("bgrqk,bkgd->bgrqd", p[..., cache_size:],
+                       v.astype(jnp.float32))
+    o = o.reshape(b, lay.h_loc, c, hd).astype(x.dtype)
+    o = o.transpose(0, 2, 1, 3).reshape(b, c, lay.h_loc * hd)
     out = pc.psum(jnp.einsum("bsn,nd->bsd", o, params["wo"]))
-    return x + out, {"k": ck, "v": cv}
+    return x + out, rows
+
+
+def is_ring(size, window):
+    """Whether a cache of ``size`` rows for a layer of sliding ``window`` is a
+    ring, row ``p % size`` holding position ``p`` (:func:`init_cache`)."""
+    return window is not None and size <= window
+
+
+def rows_specs(dp):
+    """Shard specs of :func:`apply_decode`'s rows: k/v as the cache's."""
+    return {**cache_specs(dp), "slot": P(dp, None)}
+
+
+def write_rows(cache, rows, pc, window=None):
+    """Put :func:`apply_decode`'s rows into ``cache``, in place where the
+    caller donates it; rows whose ``slot`` is out of bounds are dropped.
+    ``window``: the layer's sliding window, whose ring (:func:`is_ring`) a
+    chunk can wrap past the end of.  Any axes before the slot axis (a stack
+    of layers, whose rows share their slots) are written at once.  Each
+    shard of ``pc``'s mesh writes the slots it holds: left to the automatic
+    partitioner, a per-slot update of a cache sharded over slots gathers the
+    whole cache.  (A slot count that the data-parallel shards do not divide
+    is written whole on each.)"""
+    lead = cache["k"].ndim - 4
+    dp = pc.dp_spec() if cache["k"].shape[lead] % pc.dp == 0 else None
+
+    def stacked(specs):
+        return {n: P(*((None,) * lead + tuple(s))) for n, s in specs.items()}
+
+    cs = stacked(cache_specs(dp))
+    ring = is_ring(cache["k"].shape[-2], window)
+    return shard_map(partial(_write_rows, ring=ring), pc.mesh,
+                     in_specs=(cs, stacked(rows_specs(dp))), out_specs=cs)(cache, rows)
+
+
+def _write_rows(cache, rows, ring):
+    """:func:`write_rows` on one shard.
+
+    The TPU keeps an f32 cache of head dim 64 with ``max_len`` minor, so a
+    row is one lane of many (8, 128) tiles.  Each slot's rows are written by
+    reading the span of cache rows that holds them — from a multiple of
+    ``_SPAN``, wide enough for C rows from any start, and for a chunk that
+    wraps past a ring's end the span at row 0 too — putting the rows in, and
+    writing the span back whole.  The span is written per slot, unrolled: a
+    scatter, a gather of the rows, or a loop over slots makes XLA lay the
+    cache out with its head dim minor, padded, and copy it to and from the
+    layout that attention reads."""
+    slot = rows["slot"].reshape((-1,) + rows["slot"].shape[-2:])[0]  # [B, C]
+    n_slots, c = slot.shape
+    buf = cache["k"]
+    lead, size = buf.ndim - 4, buf.shape[-2]
+    width = min(size, -(-(c + _SPAN - 1) // _SPAN) * _SPAN)
+    window = buf.shape[:lead] + (1, buf.shape[-3], width, buf.shape[-1])
+    first = jnp.minimum(slot[:, 0], size - 1)  # a slot with no kept row: its last span
+    starts = [jnp.minimum(first // _SPAN * _SPAN, size - width)]
+    if ring and c > 1 and size > width:
+        starts.append(jnp.zeros_like(first))
+    starts = jnp.stack(starts, 1)  # [B, spans]
+    hits = (slot[:, None, :, None] == starts[:, :, None, None] + jnp.arange(width)).any(2)
+    # chunk row t lands on span row slot0 + t - start, and on a ring also on
+    # slot0 + t - size - start (past its end).  Each placement goes into the
+    # span padded by C rows on each side, at an offset clipped so that one
+    # that misses the span lies wholly in the padding.
+    shifts = jnp.asarray([0, -size] if ring else [0])
+    offsets = jnp.clip(slot[:, :1, None] - starts[:, :, None] + shifts + c, 0, width + c)
+    out = dict(cache)
+    with jax.named_scope("kv_write"):
+        for i in range(n_slots):
+            for e in range(starts.shape[1]):
+                corner = (0,) * lead + (i, 0, starts[i, e], 0)
+                for name in ("k", "v"):
+                    span = lax.dynamic_slice(out[name], corner, window)
+                    new = lax.index_in_dim(rows[name], i, lead).astype(span.dtype)
+                    if c > 1:
+                        pad = jnp.zeros(window[:-2] + (width + 2 * c, window[-1]),
+                                        span.dtype)
+                        for off in offsets[i, e]:
+                            pad = lax.dynamic_update_slice_in_dim(pad, new, off,
+                                                                  axis=lead + 2)
+                        new = lax.slice_in_dim(pad, c, c + width, axis=lead + 2)
+                    span = jnp.where(hits[i, e][:, None], new, span)
+                    out[name] = lax.dynamic_update_slice(out[name], span, corner)
+    return out
