@@ -50,8 +50,14 @@ def resolve_interpret(interpret=None):
     the TPU interpreter (``pltpu.InterpretParams``, which simulates the
     inter-device DMAs and semaphores) on the "emulated" target.  The
     emulated target has no Mosaic compiler, so an explicit ``False`` there
-    interprets too.
+    interprets too.  ``"hlo"`` asks for Pallas's plain interpreter on the
+    emulated target: it lowers the kernel to ordinary HLO with no host
+    callbacks, so it also runs under a partially automatic shard_map, where
+    the TPU interpreter's callbacks cannot; it has local DMAs, not remote
+    ones.
     """
+    if interpret == "hlo":
+        return is_emulated()
     if interpret is None:
         interpret = is_emulated()
     if isinstance(interpret, bool):

@@ -273,8 +273,10 @@ class LayerDef:
         return attention.cache_specs(dp)
 
     def apply_decode(self, params, x, cache, cache_len, pc, cfg,
-                     shared_params=None, q_valid=None):
-        """One decode chunk through this layer, reading ``cache`` only.
+                     shared_params=None, q_valid=None, layer=None):
+        """One decode chunk through this layer, reading ``cache`` only:
+        this layer's cache, or with ``layer`` (an int32 index) the stack of
+        the scan's layers, of which it reads ``layer``.
 
         Returns (x, update): for attention the chunk's new cache rows, for
         mamba the whole new state; :meth:`write_cache` applies it."""
@@ -285,6 +287,10 @@ class LayerDef:
               else jnp.asarray(q_valid, jnp.int32))
         with jax.named_scope(self.mixer_scope):
             if self.kind == "mamba":
+                if layer is not None:
+                    cache = jax.tree_util.tree_map(
+                        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+                        cache)
                 full = mamba.specs(cfg, pc.tp, pc.dp_spec())
                 sp = {k: pc.manual(v) for k, v in full.items()}
                 cs = {k: pc.manual(v) for k, v in mamba.cache_specs(pc.dp_spec()).items()}
@@ -297,17 +303,20 @@ class LayerDef:
             else:
                 full = attention.specs(cfg, pc.tp, pc.dp_spec())
                 sp = {k: pc.manual(v) for k, v in full.items()}
-                cs = {k: pc.manual(v) for k, v in
+                lead = () if layer is None else (None,)
+                cs = {k: pc.manual(P(*lead, *v)) for k, v in
                       attention.cache_specs(pc.dp_spec()).items()}
                 rs = {k: pc.manual(v) for k, v in
                       attention.rows_specs(pc.dp_spec()).items()}
+                at = jnp.int32(0) if layer is None else jnp.asarray(layer, jnp.int32)
                 x, update = pc.smap(
-                    lambda p_, x_, c_, l_, n_: attention.apply_decode(
+                    lambda p_, x_, c_, l_, n_, i_: attention.apply_decode(
                         p_, x_, c_, l_, pc, cfg, window=self.window,
-                        rope_theta=self.theta, q_valid=n_),
-                    in_specs=(sp, P(None, None, None), cs, P(None), P(None)),
+                        rope_theta=self.theta, q_valid=n_,
+                        layer=None if layer is None else i_),
+                    in_specs=(sp, P(None, None, None), cs, P(None), P(None), P()),
                     out_specs=(P(None, None, None), rs),
-                )(pc.use_gather(mixer_params, full), x, cache, lens, nv)
+                )(pc.use_gather(mixer_params, full), x, cache, lens, nv, at)
         return self.ffn_decode(params, x, pc, cfg), update
 
     def ffn_decode(self, params, x, pc, cfg):
@@ -743,15 +752,17 @@ def decode_step(params, caches, cfg, pc: ParallelContext, tokens, cache_len,
 
     new_scan = caches.get("scan")
     if n_units:
-        # the scan reads the stacked caches and returns only each layer's
-        # update; one write after it puts them in, in place
+        # each layer reads the whole stack of caches at its index (a slice
+        # taken as the scan's xs would be copied out for the decode-attention
+        # kernel) and returns only its update; one write after the scan puts
+        # them in, in place
         def unit_body(h, xs):
-            unit_params, unit_caches = xs
+            unit_params, idx = xs
             updates = []
             for i, d in enumerate(unit):
-                h, u = d.apply_decode(unit_params[i], h, unit_caches[i],
+                h, u = d.apply_decode(unit_params[i], h, caches["scan"][i],
                                       cache_len, pc, cfg, shared_params=shared,
-                                      q_valid=q_valid)
+                                      q_valid=q_valid, layer=idx)
                 updates.append(u)
             return h, updates
 
@@ -760,14 +771,13 @@ def decode_step(params, caches, cfg, pc: ParallelContext, tokens, cache_len,
                 collected = []
                 for u in range(n_units):
                     up = jax.tree_util.tree_map(lambda a: a[u], params["scan"])
-                    uc = jax.tree_util.tree_map(lambda a: a[u], caches["scan"])
-                    x, us = unit_body(x, (up, uc))
+                    x, us = unit_body(x, (up, jnp.int32(u)))
                     collected.append(us)
                 updates = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
                                                  *collected)
             else:
-                x, updates = jax.lax.scan(unit_body, x,
-                                          (params["scan"], caches["scan"]))
+                x, updates = jax.lax.scan(
+                    unit_body, x, (params["scan"], jnp.arange(n_units, dtype=jnp.int32)))
             new_scan = [d.write_cache(c, u, pc)
                         for d, c, u in zip(unit, caches["scan"], updates)]
 

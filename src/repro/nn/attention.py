@@ -27,7 +27,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro import backend
 from repro.backend import shard_map
+from repro.kernels.decode_attention import NEG_INF, decode_attention, span_rows
 from repro.nn.layers import (
     rms_norm, rope, he_init, gqa_layout, GQALayout,
 )
@@ -35,6 +37,7 @@ from repro.nn.layers import (
 __all__ = [
     "init", "specs", "grad_masks", "apply_seq", "apply_seq_ring", "apply_decode",
     "init_cache", "chunked_attention", "seam_proj", "write_rows",
+    "decode_kernel_takes", "decode_read_span",
 ]
 
 
@@ -459,12 +462,34 @@ def cache_specs(dp):
     return {"k": P(dp, "model", None, None), "v": P(dp, "model", None, None)}
 
 
+def decode_kernel_takes(c, size, window):
+    """Whether :func:`apply_decode` reads a cache of ``size`` rows with the
+    decode-attention kernel (``kernels/decode_attention.py``): one query row,
+    a cache that is no ring and whose rows fill whole (8, 128) tiles, on the
+    TPU target.  Everything else, the emulated target included, takes XLA's
+    path."""
+    return (c == 1 and not is_ring(size, window) and size % _SPAN == 0
+            and not backend.is_emulated())
+
+
+def decode_read_span(cfg, tp, size, window, dtype):
+    """Cache rows per span that a decode pass reads of each slot's cache of
+    ``size`` rows for a layer of sliding ``window``, or None where it reads
+    every row."""
+    if not decode_kernel_takes(1, size, window):
+        return None
+    lay = _lay(cfg, tp)
+    return span_rows(size, lay.kv_loc, cfg.hd, jnp.dtype(dtype).itemsize)
+
+
 def apply_decode(params, x, cache, cache_len, pc, cfg, *, window=None,
-                 rope_theta=None, q_valid=None):
+                 rope_theta=None, q_valid=None, layer=None):
     """Chunked decode body (inside manual region).
 
     x: [B, C, D] replicated over model (C == 1 is plain decode; C > 1 is a
-    prefill chunk); cache k/v: [B, kv_loc, S_max, hd] per-shard.
+    prefill chunk); cache k/v: [B, kv_loc, S_max, hd] per-shard, or with
+    ``layer`` (an int32 index) the stack of all the scan's layers
+    [n_units, B, kv_loc, S_max, hd], of which this layer reads ``layer``.
     ``cache_len`` is the number of tokens already in each slot's cache — a
     scalar or a per-slot [B] vector (the continuous-batching engine runs
     heterogeneous lengths).  ``q_valid`` ([B] int, optional) is how many of
@@ -482,6 +507,11 @@ def apply_decode(params, x, cache, cache_len, pc, cfg, *, window=None,
     ring slot another in-flight query still needs.  Query head ``g*rep + r``
     reads KV head ``g`` where it lies, with no expanded copy of the cache.
     Requires C <= cache size for ring (sliding-window) layers.
+
+    Where :func:`decode_kernel_takes`, part 1 is the decode-attention
+    kernel, which reads the stack in place and only the spans of rows that
+    are live in slots with a row to decode; otherwise XLA reads the layer's
+    whole cache.
     """
     lay = _lay(cfg, pc.tp)
     hd = cfg.hd
@@ -503,7 +533,7 @@ def apply_decode(params, x, cache, cache_len, pc, cfg, *, window=None,
     theta = rope_theta if rope_theta is not None else cfg.rope_theta
     q, k = rope(q, k, pos, theta)
 
-    cache_size = cache["k"].shape[2]
+    cache_size = cache["k"].shape[-2]
     ring = is_ring(cache_size, window)
     if ring and c > cache_size:
         raise ValueError(
@@ -519,6 +549,18 @@ def apply_decode(params, x, cache, cache_len, pc, cfg, *, window=None,
     g, rep = lay.kv_loc, lay.h_loc // lay.kv_loc
     qh = q.transpose(0, 2, 1, 3)  # [b, h_loc, C, hd]
     qf = (qh * hd ** -0.5).astype(jnp.float32).reshape(b, g, rep, c, hd)
+
+    def project(o):  # [b, g, rep, C, hd] f32 -> the block's output and rows
+        o = o.reshape(b, lay.h_loc, c, hd).astype(x.dtype)
+        o = o.transpose(0, 2, 1, 3).reshape(b, c, lay.h_loc * hd)
+        return x + pc.psum(jnp.einsum("bsn,nd->bsd", o, params["wo"])), rows
+
+    if decode_kernel_takes(c, cache_size, window):
+        return project(_decode_one_row(qf[:, :, :, 0], k[:, 0], v[:, 0], cache, layer,
+                                       lens, nv, window, pc)[:, :, :, None])
+    if layer is not None:
+        cache = {n: lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+                 for n, a in cache.items()}
     # part 1: the pre-existing cache rows (the chunk is not in them yet).
     # One query row per head is a multiply and sum in f32 that reads the
     # cache where it lies; as a product on the MXU, XLA would convert the
@@ -567,10 +609,43 @@ def apply_decode(params, x, cache, cache_len, pc, cfg, *, window=None,
         o = jnp.einsum("bgrqk,bgkd->bgrqd", p[..., :cache_size], vv)
     o = o + jnp.einsum("bgrqk,bkgd->bgrqd", p[..., cache_size:],
                        v.astype(jnp.float32))
-    o = o.reshape(b, lay.h_loc, c, hd).astype(x.dtype)
-    o = o.transpose(0, 2, 1, 3).reshape(b, c, lay.h_loc * hd)
-    out = pc.psum(jnp.einsum("bsn,nd->bsd", o, params["wo"]))
-    return x + out, rows
+    return project(o)
+
+
+def _decode_one_row(q, k, v, cache, layer, lens, nv, window, pc):
+    """:func:`apply_decode`'s attention for one query row a head, part 1 by
+    the decode-attention kernel, merged with part 2, the row's own key.
+
+    q: [B, g, rep, hd] f32, scaled; k, v: the row's own [B, g, hd].
+    Returns [B, g, rep, hd] f32."""
+    if layer is None:  # one layer's cache: a stack of one
+        cache, layer = {n: a[None] for n, a in cache.items()}, 0
+    live = nv > 0
+    lo = jnp.zeros_like(lens) if window is None else jnp.maximum(lens - window + 1, 0)
+    # a kernel is not partitioned automatically: each shard of the
+    # data-parallel axes reads the slots it holds (all, where they do not
+    # divide the slots)
+    dp = pc.dp_spec() if q.shape[0] % pc.dp == 0 else None
+    auto = set(pc.mesh.axis_names) - {pc.axis}
+    # Pallas's plain interpreter on the emulated target: the TPU
+    # interpreter's callbacks cannot run under the partially automatic
+    # shard_map of the serving step
+    read = partial(decode_attention, interpret="hlo")
+    read = read if not auto else shard_map(
+        read, jax.sharding.get_abstract_mesh(),
+        in_specs=(P(dp), P(None, dp), P(None, dp), P(), P(dp), P(dp)),
+        out_specs=P(dp), axis_names=auto)
+    with jax.named_scope("kv_read"):
+        # the cache as its swapped view [.., hd, S]: the layout it has
+        acc, m1, l1 = read(q, jnp.swapaxes(cache["k"], -1, -2),
+                           jnp.swapaxes(cache["v"], -1, -2), jnp.asarray(layer, jnp.int32),
+                           lo, jnp.where(live, lens, 0))
+    # part 2: the row's own key, which it always attends where it is valid
+    s2 = jnp.sum(q * k.astype(jnp.float32)[:, :, None], axis=-1, keepdims=True)
+    s2 = jnp.where(live[:, None, None, None], s2, NEG_INF)
+    m = jnp.maximum(m1, s2)
+    a1, a2 = jnp.exp(m1 - m), jnp.exp(s2 - m)
+    return (acc * a1 + a2 * v.astype(jnp.float32)[:, :, None]) / (l1 * a1 + a2)
 
 
 def is_ring(size, window):

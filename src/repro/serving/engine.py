@@ -16,8 +16,10 @@ advances every admitted sequence one iteration:
 
 Each step records host spans in the profiler's trace (``repro.serve.step``
 around ``repro.serve.admit``, ``.prep``, ``.dispatch``, ``.fetch`` and
-``.post``; ``.post`` carries the step's counts as arguments), and the jitted
-step names its device work (``mixed_pass``, ``decode_pass``, ``sample``).
+``.post``; ``.post`` carries the step's counts as arguments, and ``.step``
+``kv_rows_read`` beside ``kv_rows_full``, the cache rows its decode passes
+read against every row), and the jitted step names its device work
+(``mixed_pass``, ``decode_pass``, ``sample``).
 ``stats`` sums the same counts over steps, for a reader without a profiler.
 
 ``poll(handle)`` reads a request's progress, ``step()``'s return value is
@@ -31,6 +33,7 @@ which other requests share the batch or on step boundaries.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Dict, List
 
@@ -39,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import lm
+from repro.nn import attention
 from repro.serving.cache import SlotPool
 from repro.serving.scheduler import Request, Scheduler
 
@@ -93,10 +97,22 @@ class ServeEngine:
         self.pool = SlotPool(cfg, pc, self.n_slots, self.max_len,
                              self.cache_dtype)
         # sums over steps; "passes" counts the decode passes of the loop,
-        # "slots_busy" and "queued" are slot-steps and request-steps
+        # "slots_busy" and "queued" are slot-steps and request-steps;
+        # "kv_rows_read" the rows of the attention layers' caches that the
+        # decode passes read, summed over layers (in spans where attention
+        # reads only live rows), "kv_rows_full" what reading every row would be
         self.stats = {"steps": 0, "host_syncs": 0, "step_traces": 0,
                       "admitted": 0, "passes": 0, "prefill_tokens": 0,
-                      "decode_tokens": 0, "slots_busy": 0, "queued": 0}
+                      "decode_tokens": 0, "slots_busy": 0, "queued": 0,
+                      "kv_rows_read": 0, "kv_rows_full": 0}
+        # attention layers by (cache rows, window, span rows or None)
+        prefix, unit, n_units, suffix = lm.layer_plan(cfg)
+        self._kv_layers = collections.Counter()
+        for d in prefix + unit * n_units + suffix:
+            if d.kind != "mamba":
+                size = self.max_len if d.window is None else min(self.max_len, d.window)
+                self._kv_layers[size, d.window, attention.decode_read_span(
+                    cfg, pc.tp, size, d.window, self.cache_dtype)] += 1
         donate = () if jax.default_backend() == "cpu" else (1,)
         self._step_fn = jax.jit(self._build_step(), donate_argnums=donate)
         self.decode_channels = self._warm_decode_channels() if pc.tune else {}
@@ -186,7 +202,7 @@ class ServeEngine:
         Returns {handle: tokens emitted this step} — the streaming surface.
         Exactly one host sync regardless of how many tokens were decoded.
         """
-        with _span("step"):
+        with _span("step") as step_span:
             self._admit()
             sch = self.scheduler
             if not any(r is not None for r in sch.slots):
@@ -203,11 +219,13 @@ class ServeEngine:
                 counts = {"passes": int(passes), "prefill_tokens": prefill,
                           "decode_tokens": int(emitted.sum()),
                           "slots_busy": sum(r is not None for r in sch.slots)}
+                kv_rows = self._kv_rows(inputs[0] + valid, emitted, int(passes))
                 results = self._post(buf, emitted, valid)
                 counts["queued"] = len(sch.queue)
-                for k, v in counts.items():
+                for k, v in {**counts, **kv_rows}.items():
                     self.stats[k] += v
                 span.set_metadata(**counts)
+            step_span.set_metadata(**kv_rows)
             return results
 
     def _prepare(self):
@@ -252,6 +270,25 @@ class ServeEngine:
         inputs = (lens, tokens, valid, active, budget, eos, temp, topk, seeds,
                   nsamp, np.int32(n_decode))
         return inputs, valid, prefill
+
+    def _kv_rows(self, lens, emitted, passes) -> Dict[str, int]:
+        """Cache rows the step's decode passes read, summed over attention
+        layers: a slot that emitted ``e`` tokens decoded in passes 1 to
+        e - 1, in pass p with the ``lens + p - 1`` rows before its own in the
+        cache; ``lens`` are the rows after the mixed pass.  A layer that reads
+        only live rows reads, of each such slot, the spans that hold its rows
+        in the window; any other reads every row of every slot."""
+        before = np.concatenate([lens[i] + np.arange(e - 1)
+                                 for i, e in enumerate(emitted)] + [np.zeros(0, int)])
+        read = full = 0
+        for (size, window, t), layers in self._kv_layers.items():
+            full += layers * passes * self.n_slots * size
+            if t is None:
+                read += layers * passes * self.n_slots * size
+                continue
+            lo = 0 if window is None else np.maximum(before - window + 1, 0)
+            read += layers * int(np.maximum(-(-before // t) - lo // t, 0).sum()) * t
+        return {"kv_rows_read": read, "kv_rows_full": full}
 
     def _post(self, buf, emitted, valid) -> Dict[int, List[int]]:
         """Hand the fetched tokens to their requests; release finished slots."""

@@ -168,6 +168,11 @@ def _kernel_call(name):
     if name == "mamba_ssd":
         return lambda: kernels.ssd_intra_chunk(jnp.zeros((2, 32)), jnp.ones((2, 32, 32)),
                                                jnp.ones((2, 32, 16)), interpret=True)
+    if name == "decode_attention":
+        q, kv = jnp.ones((2, 1, 1, 64)), jnp.ones((1, 2, 1, 64, 128))
+        return lambda: kernels.decode_attention(q, kv, kv, 0, jnp.zeros((2,), jnp.int32),
+                                                jnp.asarray([3, 0], jnp.int32),
+                                                interpret=True)
     mesh = make_mesh((4,), ("model",))
     if name == "ag_gemm":
         fn = shard_map(lambda a, b: kernels.ag_gemm_shard(a, b, world_size=4, bn=128,
@@ -183,6 +188,6 @@ def _kernel_call(name):
 
 
 @pytest.mark.parametrize("name", ["ag_gemm", "gemm_rs", "flash_attention", "matmul",
-                                  "grouped_matmul", "mamba_ssd"])
+                                  "grouped_matmul", "mamba_ssd", "decode_attention"])
 def test_pallas_kernel_carries_its_name(name):
     assert name in scopes(jax.jit(_kernel_call(name)).lower())

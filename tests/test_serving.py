@@ -251,3 +251,56 @@ def test_engine_warms_decode_channels(pc8, mesh8):
     assert {"qkv", "attn_out", "ffn_gu", "ffn_down"} <= set(eng.decode_channels)
     for name, ch in eng.decode_channels.items():
         assert isinstance(ch, BlockChannel), name
+
+
+def _kv_rows_run(arch, pc, mesh, monkeypatch, kernel):
+    """Two requests of 6 tokens through a 1024-row cache, prompts of 9 and
+    520 tokens fed 128 a step, with the decode-attention kernel's rule
+    forced (its target left out) or never taken.  Returns the stats and
+    the number of attention layers."""
+    from repro.nn import attention
+    from repro.serving import Request
+
+    monkeypatch.setattr(attention, "decode_kernel_takes",
+                        lambda c, size, window: (kernel and c == 1 and size % 128 == 0
+                                                 and not attention.is_ring(size, window)))
+    cfg, params = _build(arch, pc, mesh)
+    eng = ServeEngine(cfg, pc, params, max_len=1024, n_slots=2, prefill_chunk=128,
+                      decode_block=8)
+    prompts = [np.arange(n, dtype=np.int32) % cfg.vocab_size for n in (9, 520)]
+    eng.drain([eng.submit(Request(tokens=p, max_new_tokens=6)) for p in prompts])
+    assert (eng.stats["steps"], eng.stats["passes"]) == (5, 10)
+    return eng.stats, cfg
+
+
+@pytest.mark.parametrize("kernel", [True, False])
+def test_kv_rows_read_counts_live_spans(pc8, mesh8, monkeypatch, kernel):
+    """``kv_rows_read`` counts the cache rows the decode passes read, in the
+    kernel's spans of 512 rows of a 1024-row cache, against ``kv_rows_full``,
+    every row of every slot in every pass; both sum over attention layers,
+    and on XLA's path the two agree.
+
+    By hand: step 1 seats both requests; the first finishes its prompt and
+    decodes 5 passes over 9..13 cached rows (one span each).  Steps 2-4 feed
+    the second's prompt; step 5 finishes it, and its 5 passes read 520..524
+    rows (two spans each)."""
+    st, cfg = _kv_rows_run("smollm-360m", pc8, mesh8, monkeypatch, kernel)
+    layers = cfg.n_layers
+    assert st["kv_rows_full"] == 10 * 2 * 1024 * layers
+    if kernel:
+        assert st["kv_rows_read"] == (5 * 512 + 5 * 2 * 512) * layers < st["kv_rows_full"]
+    else:
+        assert st["kv_rows_read"] == st["kv_rows_full"]
+
+
+def test_kv_rows_read_counts_ring_layers_whole(pc8, mesh8, monkeypatch):
+    """With sliding-window layers (gemma3: five local layers of window 1024
+    to one global, so the local caches are rings of 1024 rows), the kernel
+    reads the global layers' live spans as above and XLA every row of the
+    rings."""
+    st, cfg = _kv_rows_run("gemma3-27b", pc8, mesh8, monkeypatch, True)
+    local = sum(cfg.layer_kind(i) == "attn_local" for i in range(cfg.n_layers))
+    assert 0 < local < cfg.n_layers
+    full = 10 * 2 * 1024
+    assert st["kv_rows_full"] == full * cfg.n_layers
+    assert st["kv_rows_read"] == full * local + (5 * 512 + 5 * 2 * 512) * (cfg.n_layers - local)
