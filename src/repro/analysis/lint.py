@@ -16,7 +16,12 @@ Enforces, over ``src/repro``, the invariants the changelog states informally
   * ``raw-pallas-call`` — no raw ``pl.pallas_call`` outside ``backend/``;
                           kernels must launch through ``backend.pallas_call``
                           so the emulated/Mosaic target switch stays in one
-                          place.
+                          place;
+  * ``tune-import``     — ``repro.tune`` may be imported (at module level or
+                          inside a function) only by ``parallel/context.py``
+                          and ``tune/`` itself: the tuner sits above
+                          ``core/``, and the context is the one place the
+                          model stack chooses a design point.
 """
 from __future__ import annotations
 
@@ -44,6 +49,7 @@ _ALLOWED = {
     "ppermute-site": ("core/overlap.py",),
     "semaphore-site": ("kernels/", "backend/", "core/primitives.py"),
     "raw-pallas-call": ("backend/",),
+    "tune-import": ("parallel/context.py", "tune/"),
 }
 
 
@@ -65,11 +71,35 @@ def _allowed(rule: str, relpath: str) -> bool:
     )
 
 
+def _imports_tune(node: ast.AST) -> bool:
+    """Does this import statement bring in ``repro.tune`` or a submodule?"""
+    def is_tune(module: str) -> bool:
+        return module == "repro.tune" or module.startswith("repro.tune.")
+
+    if isinstance(node, ast.Import):
+        return any(is_tune(alias.name) for alias in node.names)
+    if isinstance(node, ast.ImportFrom) and node.module and not node.level:
+        return is_tune(node.module) or (
+            node.module == "repro" and any(a.name == "tune" for a in node.names))
+    return False
+
+
 def lint_source(source: str, relpath: str) -> List[Violation]:
     """Lint one module's source; ``relpath`` is relative to ``src/repro``."""
     violations: List[Violation] = []
     tree = ast.parse(source, filename=relpath)
     for node in ast.walk(tree):
+        if _imports_tune(node) and not _allowed("tune-import", relpath):
+            violations.append(
+                Violation(
+                    relpath,
+                    node.lineno,
+                    "tune-import",
+                    "repro.tune imported outside parallel/context.py and "
+                    "tune/ — choose the channel in ParallelContext and pass "
+                    "compile_overlap an explicit BlockChannel",
+                )
+            )
         if not isinstance(node, ast.Attribute):
             continue
         base = node.value.id if isinstance(node.value, ast.Name) else None
@@ -127,7 +157,8 @@ def lint_tree(root: Optional[Path] = None) -> List[Violation]:
 def main(argv: Optional[Sequence[str]] = None) -> int:
     p = argparse.ArgumentParser(
         prog="python -m repro.analysis.lint",
-        description="Layering lint: ppermute/semaphore/pallas_call call-sites.",
+        description="Layering lint: ppermute/semaphore/pallas_call call-sites "
+        "and repro.tune imports.",
     )
     p.add_argument("root", nargs="?", default=None, help="package root (default: src/repro)")
     args = p.parse_args(argv)

@@ -37,7 +37,7 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_dev_mesh(n_model: int = None, n_data: int = None):
-    """Small mesh over whatever devices exist (tests / examples / benchmarks)."""
+    """Small mesh over whatever devices exist (tests / examples)."""
     n = len(jax.devices())
     n_model = n_model or (2 if n >= 2 else 1)
     n_data = n_data or max(1, n // n_model)

@@ -5,8 +5,9 @@ sequence-sharded residual stream while projecting to this rank's heads (the
 paper's AG+GEMM), attention runs locally on the head shard with a
 memory-efficient chunked online-softmax (differentiable), and the output
 projection is the GEMM+RS consumer (paper Fig. 4).  Both collectives lower
-through ``compile_overlap`` as tile plans, so the tile order / channel count /
-accum dtype / wire encoding selected by ``pc.channel`` apply here uniformly.
+through ``compile_overlap`` as tile plans, under whatever design point the
+``ParallelContext`` chooses for each op (``pc.channel``, or the tuner's winner
+per shape under ``pc.tune``).
 
 Decode path (``apply_decode``): activations are replicated over the TP axis;
 projections are local column/row-parallel matmuls with a psum epilogue, and the
@@ -18,7 +19,6 @@ grad-masked so semantics match the unpadded architecture exactly.
 """
 from __future__ import annotations
 
-import dataclasses
 from functools import partial
 from typing import Optional
 
@@ -217,33 +217,22 @@ def _project_qkv(params, h, pc, lay, hd, qkv=None):
 
 
 def apply_seq(params, x, pc, cfg, *, causal=True, window=None,
-              rope_theta=None, attn_chunk=1024, return_kv=False, tune=False,
-              quant=None, qkv=None, next_proj=None, ep=None):
+              rope_theta=None, attn_chunk=1024, return_kv=False, qkv=None,
+              next_proj=None):
     """Full-sequence attention block body (call inside pc.smap manual region).
 
     x: [B, s_loc, D] sequence-sharded. Returns [B, s_loc, D] (residual added);
     with ``return_kv``, also the per-shard KV in cache layout
-    [B, kv_loc, S, hd] (prefill-into-cache).  ``tune=True`` lets the AG+GEMM
-    and GEMM+RS collectives resolve autotuned BlockChannels (repro.tune);
-    ``quant`` pins a QuantSpec wire encoding (or ``"auto"`` opens the int8
-    wire axis under ``tune=True``) — see ``ParallelContext.quant``.
+    [B, kv_loc, S, hd] (prefill-into-cache).  The AG+GEMM and GEMM+RS
+    collectives take their design point from ``pc``.
 
     Inter-op seam fusion (``pc.fuse_seams``): ``qkv`` is this layer's fused
     qkv projection already produced by the upstream op's RS->AG ring pass
     (see :func:`seam_proj`); ``next_proj=(glue, w)`` fuses the output-proj RS
     with the next consumer's AG over one shared ring pass, changing the
     return value to ``(y, next_out)`` (with ``return_kv``: ``(y, next_out,
-    kv)``).  ``ep`` is accepted for keyword-surface symmetry across the nn
-    blocks but must be falsy: attention has no expert-parallel form.
+    kv)``).
     """
-    if ep:
-        raise ValueError(
-            "attention.apply_seq has no expert-parallel form; ep= selects "
-            "the dispatch/combine a2a in moe.apply_seq only")
-    if tune and not pc.tune:
-        pc = dataclasses.replace(pc, tune=True)
-    if quant is not None and pc.quant != quant:
-        pc = dataclasses.replace(pc, quant=quant)
     lay = _lay(cfg, pc.tp)
     hd = cfg.hd
     b = x.shape[0]
@@ -276,8 +265,7 @@ def apply_seq(params, x, pc, cfg, *, causal=True, window=None,
 
 
 def apply_seq_ring(params, x, pc, cfg, *, causal=True, window=None,
-                   rope_theta=None, tune=False, quant=None, next_proj=None,
-                   ep=None):
+                   rope_theta=None, next_proj=None):
     """AG-Q + ring-KV attention block body (paper Fig. 6 layer form).
 
     Where :func:`apply_seq` gathers the WHOLE qkv projection through the
@@ -288,10 +276,8 @@ def apply_seq_ring(params, x, pc, cfg, *, causal=True, window=None,
     whose consumer honors the CompSpec tile as (block_q, block_kv).  Every
     rank attends the full query range with its local heads, so the output
     projection is the same GEMM+RS consumer as :func:`apply_seq`.
-    x: [B, s_loc, D] -> [B, s_loc, D] (residual added).  ``tune=True``
-    resolves each collective's BlockChannel (including the attention compute
-    tile) per shape via repro.tune; results match :func:`apply_seq` up to fp
-    reassociation.
+    x: [B, s_loc, D] -> [B, s_loc, D] (residual added); results match
+    :func:`apply_seq` up to fp reassociation.
 
     MQA (``kv_pad == 1``) rings the one shared head's local projection
     directly.  GQA rings per KV group: every rank gathers the (narrow)
@@ -302,14 +288,6 @@ def apply_seq_ring(params, x, pc, cfg, *, causal=True, window=None,
     heads map to.  The extra wire per tile is ``kv_pad``-fold, still far
     below the ``h``-wide AG of :func:`apply_seq`.
     """
-    if ep:
-        raise ValueError(
-            "attention.apply_seq_ring has no expert-parallel form; ep= "
-            "selects the dispatch/combine a2a in moe.apply_seq only")
-    if tune and not pc.tune:
-        pc = dataclasses.replace(pc, tune=True)
-    if quant is not None and pc.quant != quant:
-        pc = dataclasses.replace(pc, quant=quant)
     lay = _lay(cfg, pc.tp)
     hd = cfg.hd
     b, s_loc, _ = x.shape

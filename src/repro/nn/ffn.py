@@ -3,15 +3,11 @@
 Forward = AG+GEMM (gate/up fused, column-parallel) -> activation ->
 GEMM+RS (down, row-parallel): exactly the tensor-parallel MLP of paper Fig. 1.
 In overlap mode both collectives lower through ``compile_overlap`` as tile
-plans run by the generic schedule executor, so the layer inherits whatever
-tile order / channel count / accum dtype / wire encoding ``pc.channel``
-selects — or, with
-``apply_seq(..., tune=True)``, whatever the ``repro.tune`` autotuner picks
-per (kind, shape) on this mesh.
+plans run by the generic schedule executor, under whatever design point the
+``ParallelContext`` chooses for each op (``pc.channel``, or the tuner's
+winner per shape under ``pc.tune``).
 """
 from __future__ import annotations
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -52,18 +48,12 @@ def seam_proj(params, cfg):
     return (lambda y: rms_norm(y, params["ln"], cfg.norm_eps)), params["w_gu"]
 
 
-def apply_seq(params, x, pc, cfg, *, tune=False, quant=None, gu=None,
-              next_proj=None, ep=None):
+def apply_seq(params, x, pc, cfg, *, gu=None, next_proj=None):
     """x: [B, s_loc, D] -> [B, s_loc, D] (+residual). Inside manual region.
 
     Per-shard w_gu is [D, 2*f_loc] with gate|up halves interleaved per shard
-    (column-parallel), so the activation is local.  ``tune=True`` lets each
-    collective op resolve its own autotuned BlockChannel (repro.tune).
-    ``quant`` pins a :class:`~repro.core.quant.QuantSpec` wire encoding on
-    this block's collectives (or ``"auto"`` opens the int8 wire axis under
-    ``tune=True``) — see ``ParallelContext.quant``.
-    ``ep`` is accepted for keyword-surface symmetry across the nn blocks but
-    must be falsy: a dense MLP has no expert-parallel form.
+    (column-parallel), so the activation is local.  The collectives' design
+    point (channel, tuning, wire encoding) is ``pc``'s.
 
     Inter-op seam fusion (``pc.fuse_seams``): ``gu`` is this layer's gate/up
     projection already produced by the UPSTREAM op's fused RS->AG ring pass
@@ -73,14 +63,6 @@ def apply_seq(params, x, pc, cfg, *, tune=False, quant=None, gu=None,
     (e.g. the next layer norm) and ``w`` is the consumer's per-shard weight.
     With ``next_proj`` the return value is ``(y, next_out)``.
     """
-    if ep:
-        raise ValueError(
-            "ffn.apply_seq has no expert-parallel form; ep= selects the "
-            "dispatch/combine a2a in moe.apply_seq only")
-    if tune and not pc.tune:
-        pc = dataclasses.replace(pc, tune=True)
-    if quant is not None and pc.quant != quant:
-        pc = dataclasses.replace(pc, quant=quant)
     if gu is None:
         h = rms_norm(x, params["ln"], cfg.norm_eps)
         gu = pc.ag_matmul(h, params["w_gu"])  # AG + GEMM  [B, S, 2*f_loc]

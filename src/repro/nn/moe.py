@@ -5,15 +5,13 @@ Fig. 5 workload: the router fills the dynamic lookup tables; the overlapped
 "ag_rs" tile plan in core/moe_overlap.py (an AG flow of token tiles + a
 reduction riding the same permutes, run by the generic schedule executor)
 gathers token chunks and reduce-scatters combined outputs while local experts
-compute — under whatever tile order / channel count ``pc.channel`` selects,
-and with the per-expert grouped GEMMs blocked by the CompSpec (tm, tn, tk)
-tile when one is set (or tuner-resolved via ``tune=True`` — the attention/MoE
-consumers have a compute-tile axis in the joint search space).
+compute — under whatever design point the ``ParallelContext`` chooses, with
+the per-expert grouped GEMMs blocked by the channel's CompSpec (tm, tn, tk)
+tile.
 Shared experts (DeepSeek-style) run as a dense TP MLP in parallel with the
 routed path (paper §7.3 does the same for Qwen1.5's shared experts).
 
-With ``ParallelContext(ep_axis=...)`` (or ``apply_seq(..., ep=True)``) the
-routed path switches to true expert parallelism: the overlapped
+With ``ParallelContext(ep_axis=...)`` the routed path switches to true expert parallelism: the overlapped
 dispatch/combine all-to-all (``pc.a2a_moe`` -> ``core/moe_overlap.a2a_moe``),
 where token tiles and their routing tables exchange pairwise per step, local
 experts' grouped GEMMs run on landed tiles while the next exchange is in
@@ -24,8 +22,6 @@ Expert count is padded up to a multiple of the EP degree; padding experts get
 structurally — no masks needed).
 """
 from __future__ import annotations
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -72,25 +68,18 @@ def specs(cfg, tp: int, dp) -> dict:
     return s
 
 
-def apply_seq(params, x, pc, cfg, *, tune=False, quant=None, ep=None,
-              next_proj=None):
+def apply_seq(params, x, pc, cfg, *, next_proj=None):
     """x: [B, s_loc, D] -> ([B, s_loc, D], aux_loss). Inside manual region.
 
     Batch rows are routed/dispatched independently (vmap over B) so the
     DP-sharded batch dim partitions cleanly; capacity is per (batch row,
-    sequence chunk).  ``tune=True`` lets the routed exchange (and the
-    shared-expert MLP, which sees the same pc) resolve autotuned
-    BlockChannels (repro.tune).  ``quant`` pins a QuantSpec wire encoding
-    (or ``"auto"``, a no-op for the a2a exchange itself — the MoE kinds
-    carry int32 routing tables — but live for the shared-expert MLP) — see
-    ``ParallelContext.quant``.
+    sequence chunk).  The routed exchange and the shared-expert MLP take
+    their design point from ``pc``.
 
-    ``ep`` selects the expert-parallel path (``pc.a2a_moe``: overlapped
-    dispatch/combine all-to-all with the routing tables riding the token
-    tiles) instead of the TP AG+MoE double ring (``pc.ag_moe``).  It
-    defaults to whether the context opted in via
-    ``ParallelContext(ep_axis=...)``; passing ``ep=True`` without an
-    ``ep_axis`` raises.  Both paths share capacity/drop semantics.
+    A context with an ``ep_axis`` runs the expert-parallel path
+    (``pc.a2a_moe``: overlapped dispatch/combine all-to-all with the routing
+    tables riding the token tiles); otherwise the TP AG+MoE double ring
+    (``pc.ag_moe``).  Both paths share capacity/drop semantics.
 
     ``next_proj`` is accepted for keyword-surface symmetry with
     ffn/attention ``apply_seq`` but must be None: the MoE combine ends at
@@ -102,16 +91,6 @@ def apply_seq(params, x, pc, cfg, *, tune=False, quant=None, ep=None,
             "moe.apply_seq does not support next_proj: the MoE combine ends "
             "at the residual stream, so there is no RS->AG seam to fuse "
             "into a consumer")
-    if ep is None:
-        ep = pc.ep_axis is not None
-    if ep and pc.ep_axis is None:
-        raise ValueError(
-            "moe.apply_seq(ep=True) requires ParallelContext(ep_axis=...); "
-            "expert parallelism is opt-in")
-    if tune and not pc.tune:
-        pc = dataclasses.replace(pc, tune=True)
-    if quant is not None and pc.quant != quant:
-        pc = dataclasses.replace(pc, quant=quant)
     m = cfg.moe
     e_pad = params["w_gu"].shape[0] * pc.tp  # per-shard E_loc * tp
     h = rms_norm(x, params["ln"], cfg.norm_eps)
@@ -121,7 +100,7 @@ def apply_seq(params, x, pc, cfg, *, tune=False, quant=None, ep=None,
                           top_k=m.top_k, valid_experts=m.num_experts)
 
     ids, wts, aux = jax.vmap(route)(h)  # [B, s_loc, k], aux [B]
-    moe_op = pc.a2a_moe if ep else pc.ag_moe
+    moe_op = pc.a2a_moe if pc.ep_axis is not None else pc.ag_moe
     out = jax.vmap(
         lambda t, i, w: moe_op(t, i, w, params["w_gu"], params["w_down"],
                                capacity_factor=m.capacity_factor,

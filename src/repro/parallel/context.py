@@ -9,30 +9,31 @@ describing the communication/computation design point:
   (both run inside partial-auto shard_map, manual over the TP axis only;
    FSDP/DP axes stay under XLA's automatic partitioner)
 
-Every per-shard collective op lowers through ``compile_overlap`` with
-``pc.channel``, so the whole ``CommSpec x CompSpec x QuantSpec`` space (tile
-order, channel count, accum dtype, wire encoding) is selected once here and
-honored by every layer (`nn/attention.py`, `nn/ffn.py`, `nn/moe.py`,
-`nn/mamba.py`).  ``quant=`` pins a :class:`QuantSpec` on every op (wire
-dtype split from the accum dtype), or ``quant="auto"`` opens the int8 wire
-axis to the tuner.
+This is the one place the model stack chooses an op's design point.  Layers
+(`nn/attention.py`, `nn/ffn.py`, `nn/moe.py`, `nn/mamba.py`) call
+``pc.ag_matmul`` / ``pc.matmul_rs`` / ``pc.ring_attention`` / ``pc.ag_moe`` /
+``pc.a2a_moe`` / ``pc.matmul_rs_ag`` / ``pc.psum`` on *per-shard* values while
+inside a manual region entered via ``pc.smap``; each op picks its channel here
+and hands it to ``compile_overlap``, which only lowers it.
 
-With ``tune=True`` the design point is not fixed: each op resolves the best
-``BlockChannel`` for its own operand shapes through the ``repro.tune``
-autotuner over the JOINT space — the comm half (order, C, accum dtype, and
-under ``quant="auto"`` the wire dtype) and
-the compute half (the (tm, tn, tk) consumer tile) together (persistent
-per-mesh cache; trace-safe cost-model ranking, or measured winners wherever
-the cache was pre-warmed with ``repro.tune.autotune(..., ranker="measure")``).
-Non-tuned fields of ``pc.channel`` (comm resource/mode) are inherited by
-every winner.
+  tune=False   every op lowers ``pc.channel`` (order, channel count, accum
+               dtype, compute tile, wire encoding);
+  tune=True    in overlap mode each op asks the ``repro.tune`` autotuner for
+               the best channel for its own operand shapes over the JOINT
+               space — ``tune.resolve_channel`` for single ops,
+               ``tune.resolve_seq`` for the RS->AG seam (fused or the unfused
+               pair, per shape) and ``tune.resolve_a2a`` for the expert
+               dispatch/combine (fused or the bulk baseline).  Cache hits and
+               the cost model are host-side and trace-safe; measured winners
+               apply wherever the cache was pre-warmed with
+               ``tune.autotune(..., ranker="measure")``.  Fields of
+               ``pc.channel`` the search does not cover are inherited.
 
-Layers call ``pc.ag_matmul`` / ``pc.matmul_rs`` / ``pc.psum`` on *per-shard*
-values while inside a manual region entered via ``pc.smap``.  With
-``fuse_seams=True`` the model stack additionally fuses each layer's
-down-projection RS into the next consumer's AG over ONE shared ring pass
-(``pc.matmul_rs_ag`` -> ``compile_overlap`` seq form), eliminating the exposed
-collective at the inter-op seam.
+``quant=`` pins a :class:`QuantSpec` on every op (baked into ``pc.channel``),
+or ``quant="auto"`` opens the int8 wire axis to the tuner.  ``ep_axis=``
+switches MoE layers to the expert-parallel all-to-all; ``fuse_seams=True``
+has the model stack fuse each layer's down-projection RS into the next
+consumer's AG over ONE shared ring pass (``pc.matmul_rs_ag``).
 """
 from __future__ import annotations
 
@@ -44,7 +45,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro import compat, tune
 from repro.core.channels import BlockChannel
 from repro.core.compiler import compile_overlap
 from repro.core.quant import QuantSpec
@@ -76,7 +77,6 @@ class ParallelContext:
     dp_axes: Tuple[str, ...] = ("pod", "data")
     mode: str = "overlap"  # "overlap" | "baseline"
     channel: BlockChannel = None
-    seq_shard: bool = True  # sequence-parallel residual stream
     attn_p_bf16: bool = False  # cast softmax P to bf16 before P@V
                                             # (halves attention HBM traffic)
     moe_decode_stream: bool = False  # stream local experts once over all
@@ -170,24 +170,24 @@ class ParallelContext:
     # ---- per-shard collective ops (call inside smap) ---------------------------
     # every op lowers kind -> plan -> executor through the frontend; the plan
     # cache makes repeated layer calls reuse one schedule per design point
+    @property
+    def _tuned(self) -> bool:
+        return self.tune and self.mode == "overlap"
+
     def _tune_space(self):
         """The JOINT space, widened with the int8 wire axis under quant='auto'."""
-        from repro.tune import JOINT_SPACE
-
         if self.quant == "auto":
-            return dataclasses.replace(JOINT_SPACE, flows=(None, "int8"))
-        return JOINT_SPACE
+            return dataclasses.replace(tune.JOINT_SPACE, flows=(None, "int8"))
+        return tune.JOINT_SPACE
 
     def _op(self, kind: str, shapes: Tuple = ()) -> Callable:
         channel = self.channel
-        if self.tune and self.mode == "overlap" and shapes:
-            from repro.tune import resolve_channel
-
+        if self._tuned and shapes:
             # host-side: tuning-cache lookup / cost-model ranking (trace-safe);
             # the JOINT space searches both halves — comm (order, C, wire
             # dtype under quant="auto") and compute ((tm, tn, tk) consumer
             # tile) — per op shape
-            channel = resolve_channel(
+            channel = tune.resolve_channel(
                 kind, shapes=shapes, mesh=self.mesh, axis=self.axis,
                 base=self.channel, ranker=self.tune_ranker,
                 space=self._tune_space())
@@ -203,21 +203,26 @@ class ParallelContext:
         One shared ring pass; each RS segment lands on its home rank and feeds
         the consumer's per-tile compute directly (no exposed collective at the
         seam).  With ``tune=True`` the seam-aware tuner prices fused vs.
-        unfused per shape; a schedule-incompatible seam degrades loudly to the
-        unfused pair via one SeamFallbackWarning.  Returns ``(y, out)`` where
+        unfused per shape, and an unfused verdict composes the two ops; a
+        schedule-incompatible seam degrades loudly to the unfused pair via one
+        SeamFallbackWarning.  Returns ``(y, out)`` where
         ``y = residual + rs_out`` (pre-glue, for the residual stream) and
         ``out`` is the consumer's AG-matmul output.
         """
-        ops = ["matmul_rs", "ag_matmul"]
-        if self.tune and self.mode == "overlap":
-            fn = compile_overlap(
-                ops, channel="auto", axis=self.axis, mesh=self.mesh,
-                tune_ranker=self.tune_ranker, tune_base=self.channel,
-                tune_space=self._tune_space())
-        else:
-            fn = compile_overlap(
-                ops, channel=self.channel,
-                overlapped=(self.mode == "overlap"))
+        ch_rs = ch_ag = self.channel
+        if self._tuned:
+            fused, ch_rs, ch_ag = tune.resolve_seq(
+                shapes=(jnp.shape(x), jnp.shape(w1), jnp.shape(w2)),
+                mesh=self.mesh, axis=self.axis, base=self.channel,
+                ranker=self.tune_ranker, space=self._tune_space())
+            if not fused:
+                # the tuner's own verdict, not a fallback: no warning
+                out = compile_overlap("matmul_rs", ch_rs)(x, w1, **kw)
+                y = out if residual is None else residual + out
+                h = y if glue is None else glue(y)
+                return y, compile_overlap("ag_matmul", ch_ag)(h, w2, **kw)
+        fn = compile_overlap([("matmul_rs", ch_rs), ("ag_matmul", ch_ag)],
+                             overlapped=(self.mode == "overlap"))
         return fn(x, w1, w2, residual=residual, glue=glue, **kw)
 
     def matmul_rs(self, x, w, **kw):
@@ -249,20 +254,20 @@ class ParallelContext:
             raise ValueError(
                 "a2a_moe requires ParallelContext(ep_axis=...); expert "
                 "parallelism is opt-in (use ag_moe for the TP MoE path)")
-        ch = self.channel if self.ep_axis == self.axis else self.channel.with_(
-            axis=self.ep_axis)
-        ops = ["a2a_dispatch", "combine_rs"]
-        if self.tune and self.mode == "overlap":
+        ch_d = ch_c = self.channel if self.ep_axis == self.axis else (
+            self.channel.with_(axis=self.ep_axis))
+        overlapped = self.mode == "overlap"
+        if self._tuned:
             # the a2a MoE kinds are not QUANT_WIRE_KINDS, so the widened
             # space's flow axis is inert here (int32 routing tables dilute
-            # the win); _tune_space keeps the call sites uniform regardless
-            fn = compile_overlap(
-                ops, channel="auto", axis=self.ep_axis, mesh=self.mesh,
-                tune_ranker=self.tune_ranker, tune_base=ch,
-                tune_space=self._tune_space())
-        else:
-            fn = compile_overlap(
-                ops, channel=ch, overlapped=(self.mode == "overlap"))
+            # the win)
+            overlapped, ch_d, ch_c = tune.resolve_a2a(
+                shapes=tuple(jnp.shape(a) for a in (x, ids, wts, w_gu, w_down)),
+                mesh=self.mesh, axis=self.ep_axis, base=ch_d,
+                ranker=self.tune_ranker, space=self._tune_space(),
+                capacity_factor=kw.get("capacity_factor"))
+        fn = compile_overlap([("a2a_dispatch", ch_d), ("combine_rs", ch_c)],
+                             overlapped=overlapped)
         return fn(x, ids, wts, w_gu, w_down, **kw)
 
     def psum(self, x):

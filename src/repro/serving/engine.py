@@ -115,7 +115,6 @@ class ServeEngine:
                     cfg, pc.tp, size, d.window, self.cache_dtype)] += 1
         donate = () if jax.default_backend() == "cpu" else (1,)
         self._step_fn = jax.jit(self._build_step(), donate_argnums=donate)
-        self.decode_channels = self._warm_decode_channels() if pc.tune else {}
 
     # ------------------------------------------------------------------ jit'd
     def _build_step(self):
@@ -348,39 +347,6 @@ class ServeEngine:
         outs = self.drain(handles)
         gen = np.stack([outs[h] for h in handles])
         return np.concatenate([prompts, gen], axis=1)
-
-    # ------------------------------------------------------- decode tuning
-    def _warm_decode_channels(self):
-        """Resolve decode-shape joint winners for this engine's TP GEMMs.
-
-        Decode GEMMs (M == n_slots rows, 1 token) live in a different corner
-        of the joint space than prefill shapes; ``signature(..., decode=True)``
-        keys them separately so the cache holds both winners side by side.
-        """
-        from repro import tune
-        from repro.nn.attention import _lay
-
-        cfg, pc = self.cfg, self.pc
-        lay = _lay(cfg, pc.tp)
-        hd, d = cfg.hd, cfg.d_model
-        s = self.n_slots
-        gemms = {
-            "qkv": ("ag_matmul",
-                    ((s, 1, d), (d, (lay.h_loc + 2 * lay.kv_loc) * hd))),
-            "attn_out": ("matmul_rs",
-                         ((s, 1, lay.h_loc * hd), (lay.h_loc * hd, d))),
-        }
-        if cfg.d_ff:
-            f_loc = max(1, cfg.d_ff // pc.tp)
-            gemms["ffn_gu"] = ("ag_matmul", ((s, 1, d), (d, 2 * f_loc)))
-            gemms["ffn_down"] = ("matmul_rs", ((s, 1, f_loc), (f_loc, d)))
-        return {
-            name: tune.resolve_channel(
-                kind, sig=tune.signature(kind, shapes, decode=True),
-                mesh=pc.mesh, axis=pc.axis, ranker=pc.tune_ranker,
-                space=tune.JOINT_SPACE)
-            for name, (kind, shapes) in gemms.items()
-        }
 
 
 def _all_layer_defs(cfg):

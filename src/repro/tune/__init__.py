@@ -1,27 +1,32 @@
-"""Design-space autotuner over the compiled frontend (ROADMAP "Next").
+"""Design-space autotuner over the compiled frontend.
 
 The paper's core argument (§3.1) is that communication and computation tune
 *independently*: the best ``(tile order, channel count f_C, accum dtype —
 and, under a quant-widened space, the wire dtype)``
 on the comm half and the best ``(tm, tn, tk)`` consumer tile on the compute
-half both change per shape and per mesh.  PR 2 made that space uniformly
-sweepable through ``compile_overlap``; this package searches it:
+half both change per shape and per mesh.  ``compile_overlap`` lowers any
+explicit point of that space; this package chooses one:
 
     result = autotune("ag_matmul", signature=(1, 64, 32, 32), mesh=mesh)
     fn = compile_overlap("ag_matmul", result.channel)
 
-or transparently:
+    ch = resolve_channel("ag_matmul", shapes=(x.shape, w.shape), mesh=mesh,
+                         space=JOINT_SPACE)           # per call shape
+    fused, ch_rs, ch_ag = resolve_seq(shapes=..., mesh=mesh)   # RS->AG seam
+    fused, ch_d, ch_c = resolve_a2a(shapes=..., mesh=mesh)     # EP MoE pair
 
-    compile_overlap("ag_matmul", channel="auto")      # comm half, per call shape
-    compile_overlap("ag_matmul", channel="auto", comp="auto")   # joint search
+or for every op of a model:
+
     ParallelContext(mesh=mesh, tune=True)             # every op resolves joint
-    nn.ffn.apply_seq(params, x, pc, cfg, tune=True)   # per-layer opt-in
+
+The tuner sits above ``core/``: only ``parallel/context.py`` calls it from
+the model stack (the ``tune-import`` lint rule holds that line).
 
 ``DEFAULT_SPACE`` sweeps the comm half only; ``JOINT_SPACE`` adds the
 pruned compute-tile lattice (``tune/candidates.py``) — shape-, VMEM- and
 MXU-alignment-constrained via the ``repro.backend`` hardware probes;
 ``QUANT_SPACE`` additionally opens the wire-dtype (flow) axis for the
-``QUANT_WIRE_KINDS`` (``compile_overlap(..., quant="auto")``).
+``QUANT_WIRE_KINDS`` (``ParallelContext(tune=True, quant="auto")``).
 
 Rankers
 -------
@@ -353,7 +358,7 @@ def resolve_seq(
     ranker: Optional[str] = None,
     space: Space = DEFAULT_SPACE,
 ) -> Tuple[bool, BlockChannel, BlockChannel]:
-    """Seam-aware resolution for ``compile_overlap([...], channel="auto")``.
+    """Seam-aware resolution for the ``["matmul_rs", "ag_matmul"]`` seam.
 
     Returns ``(fused, ch_rs, ch_ag)``: whether to run the fused seam, and the
     channel for each half.  The fused plan is priced over the shared-channel
@@ -415,8 +420,7 @@ def resolve_a2a(
     capacity_factor: Optional[float] = None,
     imbalance: Optional[float] = None,
 ) -> Tuple[bool, BlockChannel, BlockChannel]:
-    """Joint resolution for ``compile_overlap(["a2a_dispatch", "combine_rs"],
-    channel="auto")``.
+    """Joint resolution for the ``["a2a_dispatch", "combine_rs"]`` pair.
 
     Returns ``(fused, ch_dispatch, ch_combine)``: whether to run the
     overlapped expert-parallel pipeline, and the channel for each half.
@@ -486,8 +490,8 @@ def resolve_channel(
     """Tuned ``BlockChannel`` for an op call — the transparent entry point.
 
     Cache hits and model ranking are pure host-side work, so this is safe at
-    trace time (which is where ``compile_overlap(kind, channel="auto")`` and
-    ``ParallelContext(tune=True)`` land).  Non-tuned fields (comm resource,
+    trace time (which is where ``ParallelContext(tune=True)`` calls it).
+    Non-tuned fields (comm resource,
     mode, tiles) are inherited from ``base``.
     """
     if sig is None:

@@ -24,11 +24,10 @@ and feasibility-aware:
 
 ``DEFAULT_SPACE`` sweeps the comm half only (the compute tile stays the
 backend-chosen default) — the PR-3 contract.  ``JOINT_SPACE`` adds the
-pruned (tm, tn, tk) lattice; ``compile_overlap(..., comp="auto")`` and
-``ParallelContext(tune=True)`` search it.  ``QUANT_SPACE`` additionally
-opens the wire-dtype (flow) axis — ``QuantSpec`` per candidate, enumerated
-only for the ``QUANT_WIRE_KINDS`` — which ``compile_overlap(...,
-quant="auto")`` searches.
+pruned (tm, tn, tk) lattice; ``ParallelContext(tune=True)`` searches it.
+``QUANT_SPACE`` additionally opens the wire-dtype (flow) axis — ``QuantSpec``
+per candidate, enumerated only for the ``QUANT_WIRE_KINDS`` — which
+``ParallelContext(tune=True, quant="auto")`` searches.
 """
 from __future__ import annotations
 
@@ -123,7 +122,7 @@ class Space:
     # wire-dtype (flow) axis: None = inherit the channel's QuantSpec (for a
     # bare channel, the accum dtype — legacy pricing).  Kept (None,) by
     # default so an existing sweep's identity does not change; widened by
-    # QUANT_SPACE / compile_overlap(..., quant="auto").  Only the
+    # QUANT_SPACE / ParallelContext(quant="auto").  Only the
     # QUANT_WIRE_KINDS enumerate it.
     flows: Tuple[Optional[str], ...] = (None,)
 
@@ -243,7 +242,7 @@ def comp_tile_candidates(
     probed budget.  ``DEFAULT_TILE`` is a sentinel ("backend-chosen
     blocking", what every op runs with when untuned) and passes through
     unclamped and unpruned.  A single-tile space is an *explicit* request
-    (``compile_overlap(..., comp=<CompSpec>)``): its point is clamped but
+    (``Space(comp_tiles=(tile,))``): its point is clamped but
     never pruned — the kernels themselves clamp identically, so honoring it
     matches what an explicit channel would run.  Every tunable kind has a
     tile axis (the per-kind dims live in :func:`_tile_dims`); unknown kinds
@@ -365,20 +364,13 @@ def _moe_axes(imbalance, capacity) -> Tuple[int, ...]:
     return axes
 
 
-def signature(kind: str, shapes: Sequence[Tuple[int, ...]],
-              decode: bool = False, *, imbalance=None,
-              capacity=None) -> Tuple[int, ...]:
+def signature(kind: str, shapes: Sequence[Tuple[int, ...]], *,
+              imbalance=None, capacity=None) -> Tuple[int, ...]:
     """Canonical shape signature from *per-shard* operand shapes.
 
     Takes the positional operand shapes exactly as the ``compile_overlap``
     ops receive them inside the manual region, and keeps only what changes
     the tuning landscape (leading batch dims collapse into one).
-
-    ``decode=True`` marks a GEMM-kind signature as a *decode shape*: the
-    lead element is negated, so tiny-M decode GEMMs key their own cache
-    entries (and resolve their own joint winners) instead of aliasing the
-    prefill entry for the same dims.  Cost-model consumers read
-    ``abs(sig[0])``; the tile lattice never reads the lead at all.
 
     MoE kinds (``ag_moe`` and the ``seq_a2a_moe`` pair) may append the
     optional quantized workload axes ``imbalance``/``capacity`` (see
@@ -387,18 +379,13 @@ def signature(kind: str, shapes: Sequence[Tuple[int, ...]],
     it), so they are part of a result's identity.  Every signature consumer
     slices the shape half with ``sig[:5]``.
     """
-    if decode and kind not in GEMM_TILE_KINDS:
-        raise ValueError(
-            f"decode signatures are defined for the GEMM kinds "
-            f"{GEMM_TILE_KINDS}, not {kind!r}")
     if (imbalance is not None or capacity is not None) and kind not in MOE_SIG_KINDS:
         raise ValueError(
             f"imbalance/capacity signature axes are defined for the MoE "
             f"kinds {MOE_SIG_KINDS}, not {kind!r}")
 
     def _lead(x):
-        lead = math.prod(x[:-2]) if len(x) > 2 else 1
-        return -lead if decode else lead
+        return math.prod(x[:-2]) if len(x) > 2 else 1
 
     if kind == SEQ_KIND:
         x, w1, w2 = shapes[0], shapes[1], shapes[2]

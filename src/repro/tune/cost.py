@@ -62,7 +62,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Dict, Tuple
+from typing import Tuple
 
 import jax.numpy as jnp
 
@@ -74,7 +74,6 @@ from repro.tune.candidates import (
     GEMM_TILE_KINDS,
     _tile_dims,
     a2a_sigs,
-    chunk_extent,
     seq_sigs,
 )
 
@@ -191,12 +190,10 @@ def step_terms(
             "float32", "bfloat16", "float16") else 0.0
     if kind == "ag_matmul":
         lead, m_loc, k, n_loc = sig
-        lead = abs(lead)  # decode signatures carry a negated lead marker
         wire = lead * m_loc * k * tb + extra
         flops = 2.0 * lead * m_loc * k * n_loc
     elif kind == "matmul_rs":
         lead, m_glob, k_loc, n = sig
-        lead = abs(lead)
         m_loc = max(1, m_glob // world)
         wire = lead * m_loc * n * fb + extra  # the accumulator is the flow
         flops = 2.0 * lead * m_loc * k_loc * n
@@ -288,7 +285,7 @@ def comp_step_time(kind: str, sig: Tuple[int, ...], world: int, cand: Candidate)
 
     if kind in GEMM_TILE_KINDS:
         eff = (min(tm, mxu) / mxu) * (min(tn, mxu) / mxu)
-        lead = max(1, abs(int(sig[0])))  # decode sigs negate the lead
+        lead = max(1, int(sig[0]))
         # all C channels run their blocks each step
         blocks_mn = (m // tm) * (n // tn) * nch * lead
         n_tiles = blocks_mn * (k // tk)
@@ -439,19 +436,3 @@ def predict_a2a_cost(
     if fused:
         total -= a2a_saving(sig, world, cand)
     return total
-
-
-def explain(kind: str, sig: Tuple[int, ...], world: int, cand: Candidate) -> Dict[str, float]:
-    """Itemized terms for reports/benchmarks (same math as predict_cost)."""
-    wire, flops = step_terms(kind, sig, world, cand.accum_dtype, cand.flow)
-    ext = chunk_extent(kind, sig)
-    out = {
-        "wire_bytes_per_step": wire,
-        "flops_per_step": flops,
-        "chunk_extent": float(ext),
-        "comp_step_s": comp_step_time(kind, sig, world, cand),
-        "predicted_s": predict_cost(kind, sig, world, cand),
-    }
-    if _tile_dims(kind, tuple(sig), world, max(1, cand.num_channels)) is not None:
-        out["realized_tile"] = realized_tile(kind, sig, world, cand)
-    return out

@@ -39,9 +39,10 @@ from jax.sharding import PartitionSpec as P
 
 from repro import compat
 from repro.core.channels import BlockChannel
+from repro.core.compiler import compile_overlap
 from repro.tune.candidates import TUNABLE_KINDS
 
-__all__ = ["build_case", "measure_channel", "time_fn", "CaseTimer"]
+__all__ = ["build_case", "time_fn", "CaseTimer"]
 
 
 def _aot_compile(fn, *args):
@@ -97,8 +98,6 @@ def build_case(kind: str, mesh, axis: str, sig: Tuple[int, ...]):
     operands are synthesized deterministically so repeated measurements of
     the same signature are comparable.
     """
-    from repro.core.compiler import compile_overlap  # late: avoid import cycle
-
     world = int(mesh.shape[axis])
     key = jax.random.PRNGKey(0)
 
@@ -187,16 +186,3 @@ class CaseTimer:
     def time(self, channel: BlockChannel, *, repeats: int = 3, warmup: int = 1):
         """``(median_us, iqr_us)`` for one realized candidate."""
         return time_fn(self._build(channel), *self._args, repeats=repeats, warmup=warmup)
-
-
-def measure_channel(
-    kind: str,
-    channel: BlockChannel,
-    mesh,
-    sig: Tuple[int, ...],
-    *,
-    repeats: int = 3,
-    warmup: int = 1,
-) -> Tuple[float, float]:
-    """``(median_us, iqr_us)`` of one realized candidate on ``mesh``."""
-    return CaseTimer(kind, mesh, channel.axis, sig).time(channel, repeats=repeats, warmup=warmup)

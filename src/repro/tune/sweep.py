@@ -25,7 +25,7 @@ autotuners in PAPERS.md):
 can substitute a deterministic oracle — on the emulated CPU target wall time
 is not a perf signal (ROADMAP), but the pruning *algorithm* (prefix size,
 early exit, winner agreement with the exhaustive sweep) is deterministic and
-is asserted in ``benchmarks/autotune_bench.py --smoke``.
+is asserted in ``tests/test_tune.py``.
 
 Environment knobs (also surfaced in README.md):
 

@@ -122,21 +122,21 @@ def _moe_cfg(num_experts=8):
                                      num_shared=0))
 
 
-def _run_moe_layer(pc, cfg, params, x, *, ep=None):
+def _run_moe_layer(pc, cfg, params, x):
     from repro.nn import moe
 
     specs = moe.specs(cfg, pc.tp, None)
     in_specs = (jax.tree_util.tree_map(
         pc.manual, specs, is_leaf=lambda v: isinstance(v, P)),
         P(None, "model", None))
-    sm = pc.smap(lambda p, xx: moe.apply_seq(p, xx, pc, cfg, ep=ep),
+    sm = pc.smap(lambda p, xx: moe.apply_seq(p, xx, pc, cfg),
                  in_specs, (P(None, "model", None), P()))
     return jax.jit(sm)(params, x)
 
 
 @pytest.mark.parametrize("num_experts", (8, 6))
 def test_nn_moe_ep_path(mesh4, num_experts):
-    """moe.apply_seq(ep=True) == the EP baseline — including when the expert
+    """moe.apply_seq under ep_axis == the EP baseline — including when the expert
     count pads up to the EP degree (num_experts=6 -> e_pad=8) and the aux
     loss must only see the valid experts."""
     from repro.nn import moe
@@ -147,8 +147,8 @@ def test_nn_moe_ep_path(mesh4, num_experts):
     params = moe.init(jax.random.PRNGKey(0), cfg, pc.tp, jnp.float32)
     x = jax.random.normal(KEY, (1, R * 8, cfg.d_model), jnp.float32)
 
-    y_o, aux_o = _run_moe_layer(pc, cfg, params, x)  # ep defaults on via ep_axis
-    y_b, aux_b = _run_moe_layer(pc_b, cfg, params, x, ep=True)
+    y_o, aux_o = _run_moe_layer(pc, cfg, params, x)  # EP on via ep_axis
+    y_b, aux_b = _run_moe_layer(pc_b, cfg, params, x)
     allclose(y_o, y_b, **_tol("float32"))
     # routing (and thus the aux loss) is path-independent; under padding the
     # aux must be computed over the valid experts only, and stay finite
@@ -157,30 +157,41 @@ def test_nn_moe_ep_path(mesh4, num_experts):
 
     # the TP double-ring path still works side by side and agrees (no drops
     # at the generous reduced-config capacity)
-    y_t, aux_t = _run_moe_layer(pc, cfg, params, x, ep=False)
+    y_t, aux_t = _run_moe_layer(dataclasses.replace(pc, ep_axis=None), cfg, params, x)
     allclose(y_t, y_o, atol=2e-3, rtol=2e-3)
     np.testing.assert_allclose(np.asarray(aux_t), np.asarray(aux_o), rtol=1e-6)
 
 
 def test_unified_apply_seq_keyword_surface(mesh4):
-    """Satellite: one keyword surface (tune=, next_proj=, ep=) across the nn
-    blocks — ep is MoE-only, next_proj is seam-capable blocks only."""
+    """The nn blocks take no policy keywords: the design point (tune, quant)
+    and expert parallelism (ep_axis) are the ParallelContext's alone.  The
+    keywords left carry behaviour — the fused seam (next_proj, gu, qkv)."""
+    import inspect
+
     from repro.nn import attention, ffn, moe
+
+    def keywords(fn):
+        return {n for n, p in inspect.signature(fn).parameters.items()
+                if p.kind is p.KEYWORD_ONLY}
+
+    assert keywords(ffn.apply_seq) == {"gu", "next_proj"}
+    assert keywords(moe.apply_seq) == {"next_proj"}
+    assert keywords(attention.apply_seq) == {
+        "causal", "window", "rope_theta", "attn_chunk", "return_kv", "qkv", "next_proj"}
+    assert keywords(attention.apply_seq_ring) == {
+        "causal", "window", "rope_theta", "next_proj"}
 
     cfg = _moe_cfg()
     pc = ParallelContext(mesh=mesh4)  # no ep_axis: EP not opted in
     x = jnp.zeros((1, R * 8, cfg.d_model), jnp.float32)
 
-    with pytest.raises(ValueError, match="ep_axis"):
-        moe.apply_seq({}, x, pc, cfg, ep=True)
+    for fn in (moe.apply_seq, ffn.apply_seq, attention.apply_seq,
+               attention.apply_seq_ring):
+        for policy in ("tune", "quant", "ep"):
+            with pytest.raises(TypeError, match=policy):
+                fn({}, x, pc, cfg, **{policy: True})
     with pytest.raises(ValueError, match="next_proj"):
         moe.apply_seq({}, x, pc, cfg, next_proj=(lambda y: y, None))
-    with pytest.raises(ValueError, match="expert-parallel"):
-        ffn.apply_seq({}, x, pc, cfg, ep=True)
-    with pytest.raises(ValueError, match="expert-parallel"):
-        attention.apply_seq({}, x, pc, cfg, ep=True)
-    with pytest.raises(ValueError, match="expert-parallel"):
-        attention.apply_seq_ring({}, x, pc, cfg, ep=True)
     with pytest.raises(ValueError, match="not a mesh axis"):
         ParallelContext(mesh=mesh4, ep_axis="experts")
     with pytest.raises(ValueError, match="ep_axis"):
@@ -250,12 +261,12 @@ def test_verify_cli_covers_a2a_kinds():
 
 def test_compile_overlap_a2a_list_form(mesh4):
     """The list form compiles the pair; pallas and comp= stay structured
-    errors like the RS->AG seam."""
+    errors like the RS->AG seam (comp= names the channel that replaces it)."""
     with pytest.raises(NotImplementedError, match="a2a_dispatch"):
         compile_overlap(["a2a_dispatch", "combine_rs"], backend="pallas")
     with pytest.raises(NotImplementedError, match="combine_rs', 'a2a_dispatch"):
         compile_overlap(["combine_rs", "a2a_dispatch"])
-    with pytest.raises(ValueError, match="single-kind"):
+    with pytest.raises(TypeError, match=r"channel\.with_\(comp="):
         compile_overlap(["a2a_dispatch", "combine_rs"], comp=(8, 8, 8))
 
     # channel=None compiles with the default channel
@@ -278,16 +289,25 @@ def test_compile_overlap_a2a_list_form(mesh4):
 
 
 def test_compile_overlap_a2a_auto_channel(mesh4):
-    """channel='auto' resolves the pair jointly (model-ranked) and matches
-    the baseline numerically within the winner's flow-dtype tolerance."""
+    """resolve_a2a resolves the pair jointly (model-ranked) per call shape;
+    lowered explicitly, it matches the baseline numerically within the
+    winner's flow-dtype tolerance.  A string channel is refused by name."""
+    from repro import backend, tune
+
     e, k_top = 8, 2
     x, wr, wgu, wdn = _operands(R * 16)
-    fn = compile_overlap(["a2a_dispatch", "combine_rs"], channel="auto",
-                         axis="model", capacity_factor=8.0)
+    with pytest.raises(ValueError, match="resolve_a2a"):
+        compile_overlap(["a2a_dispatch", "combine_rs"], channel="auto")
 
     def f_(xs, wgu_, wdn_):
         ids, wts, _ = moe_router(xs, wr, num_experts=e, top_k=k_top)
-        return fn(xs, ids, wts, wgu_, wdn_)
+        args = (xs, ids, wts, wgu_, wdn_)
+        fused, ch_d, ch_c = tune.resolve_a2a(
+            shapes=[a.shape for a in args], axis="model",
+            world=int(backend.axis_size("model")), capacity_factor=8.0)
+        fn = compile_overlap([("a2a_dispatch", ch_d), ("combine_rs", ch_c)],
+                             overlapped=fused, capacity_factor=8.0)
+        return fn(*args)
 
     sm = shard_map(f_, mesh4,
                    in_specs=(P("model", None), P("model", None, None),
@@ -368,3 +388,34 @@ def test_resolve_a2a_joint(mesh4):
     for cand in tune.enumerate_a2a_candidates(sig=sig, world=R):
         assert (cost.predict_a2a_cost(sig, R, cand, fused=True)
                 <= cost.predict_a2a_cost(sig, R, cand, fused=False))
+
+
+# MoE a2a signatures (m_loc, d_model, top_k, e_loc, d_expert) per shard at
+# world 4: deepseek-moe-16b routes top-6 of 64 experts, granite-3b-a800m
+# top-8 of 40
+MOE_SIGS = {
+    "small": (32, 16, 2, 2, 8),
+    "deepseek-16b": (512, 256, 6, 16, 176),
+    "granite-3b": (512, 192, 8, 10, 64),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MOE_SIGS))
+def test_fused_a2a_beats_split_pair_modeled(name):
+    """On every MoE shape the best fused dispatch/combine beats the best
+    split pair on the modeled scale, and resolve_a2a verdicts fused with one
+    shared channel for both halves."""
+    from repro import tune
+    from repro.tune import cost
+
+    sig = MOE_SIGS[name]
+    cands = tune.enumerate_a2a_candidates(sig=sig, world=R)
+    assert cands
+
+    def best(fused):
+        return min(cost.predict_a2a_cost(sig, R, c, fused=fused) for c in cands)
+
+    assert best(True) < best(False)
+    fused, ch_d, ch_c = tune.resolve_a2a(sig=sig, world=R)
+    assert fused and ch_d == ch_c
+

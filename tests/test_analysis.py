@@ -265,6 +265,33 @@ def test_lint_flags_semaphores_outside_kernels():
     assert repro_lint.lint_source("pltpu.semaphore_signal(s)\n", "backend/lowering.py") == []
 
 
+@pytest.mark.parametrize("relpath,allowed", [
+    ("core/compiler.py", False),
+    ("kernels/ag_gemm.py", False),
+    ("nn/ffn.py", False),
+    ("models/lm.py", False),
+    ("serving/engine.py", False),
+    ("parallel/context.py", True),
+    ("tune/cost.py", True),
+])
+def test_lint_tune_import_only_above_core(relpath, allowed):
+    """repro.tune is imported only by parallel/context.py and tune/ — in any
+    import form, at module level or inside a function."""
+    forms = (
+        "from repro import tune\n",
+        "import repro.tune\n",
+        "from repro.tune import resolve_channel\n",
+        "from repro.tune.cost import predict_cost\n",
+        "def f():\n    from repro.tune import resolve_seq\n",
+    )
+    for src in forms:
+        rules = [v.rule for v in repro_lint.lint_source(src, relpath)]
+        assert rules == ([] if allowed else ["tune-import"]), (src, relpath)
+    # near misses are not the tuner
+    assert repro_lint.lint_source("from repro import tuned\nimport repro.tuner\n",
+                                  relpath) == []
+
+
 def test_lint_flags_raw_pallas_call():
     bad = repro_lint.lint_source("pl.pallas_call(k, grid=(1,))\n", "kernels/new.py")
     assert [v.rule for v in bad] == ["raw-pallas-call"]

@@ -381,6 +381,8 @@ def test_seam_unsupported_sequences_raise_structured():
     with pytest.raises(NotImplementedError, match="backend='pallas'"):
         compile_overlap(["matmul_rs", "ag_matmul"], backend="pallas")
     with pytest.raises(ValueError, match="single-kind"):
+        compile_overlap(["matmul_rs", "ag_matmul"], interpret=True)
+    with pytest.raises(TypeError, match=r"channel\.with_\(comp="):
         compile_overlap(["matmul_rs", "ag_matmul"], comp=(8, 8, 8))
 
 

@@ -201,7 +201,7 @@ def test_int8_flow_parity_on_mesh(mesh4, kind):
     ch = BlockChannel(axis="model")
     y_f = _run(mesh4, compile_overlap(kind, ch), x, w)
     y_q = _run(mesh4, compile_overlap(
-        kind, ch, quant=QuantSpec(wire_dtype="int8")), x, w)
+        kind, ch.with_(quant=QuantSpec(wire_dtype="int8"))), x, w)
     rel = float(jnp.linalg.norm(y_q - y_f) / jnp.linalg.norm(y_f))
     assert rel < 0.05, rel
 
@@ -315,8 +315,8 @@ def test_cache_v3_records_retune():
 
 
 def test_autotune_explores_flow_axis(tmp_path, mesh4, monkeypatch):
-    """channel='auto' with quant enabled must consider int8 wires and record
-    the winner's flow in a schema-4 entry."""
+    """Tuning over the quant-widened space must consider int8 wires and
+    record the winner's flow in a schema-4 entry."""
     monkeypatch.setenv("REPRO_TUNE_CACHE", str(tmp_path))
     from repro.tune import autotune
     from repro.tune.candidates import QUANT_SPACE
@@ -327,3 +327,54 @@ def test_autotune_explores_flow_axis(tmp_path, mesh4, monkeypatch):
     assert result.channel.quant is not None
     records = list(tmp_path.rglob("*.json*"))
     assert records, "autotune must persist a cache entry"
+
+
+# comm-bound GEMM signatures at world 4: narrow contraction per byte moved,
+# so the wire gates the pipeline.  matmul_rs sigs are (lead, m_glob, k_loc,
+# n); ag_matmul sigs are (lead, m_loc, k, n_loc).
+COMM_BOUND = {
+    "rs-long-seq": ("matmul_rs", (1, 2048, 32, 2048)),
+    "rs-wide-out": ("matmul_rs", (1, 1024, 128, 4096)),
+    "ag-deep-k": ("ag_matmul", (1, 512, 4096, 512)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(COMM_BOUND))
+def test_int8_wire_beats_full_precision_modeled(name):
+    """On a comm-bound shape the best int8-wire candidate beats the best
+    full-precision one on the modeled scale: the int8 wire quarters the
+    bytes on the wire at a fixed scale-table overhead."""
+    from repro.tune import cost
+    from repro.tune.candidates import QUANT_SPACE, enumerate_candidates
+
+    kind, sig = COMM_BOUND[name]
+    cands = enumerate_candidates(kind, space=QUANT_SPACE, sig=sig, world=4)
+
+    def best(flow):
+        return min(cost.predict_cost(kind, sig, 4, c) for c in cands if c.flow == flow)
+
+    assert best("int8") < best(None)
+
+
+def test_schema3_record_retunes_into_schema4(tmp_path):
+    """A stored schema-3 record (no flow axis) is re-tuned, not reused, and
+    rewritten as a schema-4 entry that carries the winner's flow — int8 on a
+    comm-bound shape."""
+    from repro.tune import CACHE_SCHEMA, _entry_key, autotune
+    from repro.tune import cache as tune_cache
+    from repro.tune.candidates import QUANT_SPACE
+
+    kind, sig = COMM_BOUND["rs-long-seq"]
+    fp = tune_cache.mesh_fingerprint(None, axis="model", world=4)
+    key = _entry_key(kind, "model", 4, sig, QUANT_SPACE)
+    v3 = {"schema": 3, "kind": kind, "signature": list(sig), "world": 4,
+          "order": "ring", "num_channels": 1, "accum_dtype": "float32",
+          "comp_tile": [64, 128, 128], "ranker": "model", "score": 1.0}
+    tune_cache.store_entry(fp, key, v3, directory=str(tmp_path))
+    res = autotune(kind, signature=sig, world=4, ranker="model",
+                   space=QUANT_SPACE, cache_dir=str(tmp_path))
+    assert not res.cache_hit and res.candidate.flow == "int8"
+    tune_cache.clear_memo()
+    rec = tune_cache.load_entry(fp, key, directory=str(tmp_path))
+    assert rec["schema"] == CACHE_SCHEMA and rec["flow"] == "int8"
+

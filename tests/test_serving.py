@@ -238,21 +238,6 @@ def test_engine_gqa_and_sampling(pc8, mesh8):
     np.testing.assert_array_equal(list(a.values())[0], b[hs[0]])
 
 
-def test_engine_warms_decode_channels(pc8, mesh8):
-    """With tuning on, engine construction resolves decode-shape joint
-    winners (decode=True signatures, keyed apart from prefill) for its TP
-    GEMMs (decode-tuning satellite; the winner-differs guarantee at real
-    dims is pinned in test_tune.py)."""
-    from repro.core.channels import BlockChannel
-
-    cfg, params = _build("smollm-360m", pc8, mesh8)
-    pc_t = dataclasses.replace(pc8, tune=True)
-    eng = ServeEngine(cfg, pc_t, params, max_len=32)
-    assert {"qkv", "attn_out", "ffn_gu", "ffn_down"} <= set(eng.decode_channels)
-    for name, ch in eng.decode_channels.items():
-        assert isinstance(ch, BlockChannel), name
-
-
 def _kv_rows_run(arch, pc, mesh, monkeypatch, kernel):
     """Two requests of 6 tokens through a 1024-row cache, prompts of 9 and
     520 tokens fed 128 a step, with the decode-attention kernel's rule

@@ -1,4 +1,4 @@
-"""Autotuner subsystem: enumeration, cache contract, channel="auto" parity.
+"""Autotuner subsystem: enumeration, cache contract, tuned-channel parity.
 
 The contract under test (ISSUE 3 + ISSUE 4 + ISSUE 5 acceptance):
   * candidate enumeration is deterministic and honors
@@ -16,20 +16,25 @@ The contract under test (ISSUE 3 + ISSUE 4 + ISSUE 5 acceptance):
   * a mesh-fingerprint mismatch invalidates (re-tunes) instead of silently
     reusing another mesh's winner;
   * a fingerprint hit never re-measures;
-  * ``channel="auto"`` / ``comp="auto"`` output is parity-equal to the
-    default-tile path on both backends on the 4-rank emulated mesh, for the
-    GEMM kinds and the tiled attention/MoE consumers alike.
+  * a channel the tuner resolves per call shape (``resolve_channel`` inside
+    shard_map, or ``ParallelContext(tune=True)``), lowered explicitly through
+    ``compile_overlap``, is parity-equal to the default-tile path on both
+    backends on the 4-rank emulated mesh, for the GEMM kinds and the tiled
+    attention/MoE consumers alike;
+  * ``compile_overlap`` lowers explicit channels only: the removed tuning
+    forms raise an error that names their replacement.
 """
 import dataclasses
 import json
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import tune
+from repro import backend, tune
 from repro.compat import make_mesh, shard_map
 from repro.core import BlockChannel, CompSpec, compile_overlap, effective_channels
 from repro.core.comp_tiles import DEFAULT_TILE, blocked_dot
@@ -109,42 +114,6 @@ def test_signature_canonicalization():
     assert att == (1, 4, 2, 16, 8)
     sig = tune.signature("ag_moe", [(16, 8), (16, 2), (16, 2), (4, 8, 32), (4, 16, 8)])
     assert sig == (16, 8, 2, 4, 16)
-
-
-def test_decode_signature_keys_separately():
-    """decode=True negates the lead ONLY — same dims, disjoint cache entries
-    for every tiny-M decode shape (serving-engine satellite)."""
-    from repro.tune import _entry_key
-
-    shapes = [(4, 1, 512), (512, 512)]
-    sig_p = tune.signature("ag_matmul", shapes)
-    sig_d = tune.signature("ag_matmul", shapes, decode=True)
-    assert sig_d == (-sig_p[0],) + sig_p[1:]
-    keys = set()
-    for m in range(1, 9):  # M = 1..8 decode slots, each its own corner
-        s = [(m, 1, 512), (512, 512)]
-        for decode in (False, True):
-            sig = tune.signature("ag_matmul", s, decode=decode)
-            keys.add(_entry_key("ag_matmul", "model", 4, sig, tune.JOINT_SPACE))
-    assert len(keys) == 16  # 8 decode + 8 prefill, no aliasing
-    # non-GEMM kinds have no decode corner — refuse loudly
-    with pytest.raises(ValueError, match="decode signatures"):
-        tune.signature("ag_attention", [(1, 4, 16, 8), (1, 2, 16, 8)], decode=True)
-
-
-def test_decode_winner_differs_from_prefill(mesh4):
-    """The decode corner (n_slots rows of ONE token) must resolve a different
-    joint winner than the prefill shape sharing its K/N dims — on the
-    analytic ranker, no device timing (serving-engine satellite)."""
-    for kind, pre_sig, dec_sig in [
-        ("ag_matmul", (1, 1024, 512, 512), (-8, 1, 512, 512)),
-        ("matmul_rs", (1, 1024, 128, 512), (-8, 1, 128, 512)),
-    ]:
-        pre = tune.autotune(kind, signature=pre_sig, mesh=mesh4, space=tune.JOINT_SPACE)
-        dec = tune.autotune(kind, signature=dec_sig, mesh=mesh4, space=tune.JOINT_SPACE)
-        assert dec.channel.comp.tile[0] == 1  # one-token tiles for decode
-        assert (pre.channel.comp.tile != dec.channel.comp.tile
-                or pre.channel.num_channels != dec.channel.num_channels), kind
 
 
 # ---- joint space: compute-tile lattice (ISSUE 4) ----------------------------
@@ -610,14 +579,19 @@ def test_measured_record_carries_sweep_stats(mesh4, monkeypatch):
 # ---- tiled attention/MoE consumers: parity on both backends (ISSUE 5) --------
 
 
+def _with_tile(ch, tile):
+    """``ch`` with its compute tile replaced (accum dtype kept); None = as is."""
+    return ch if tile is None else ch.with_(comp=dataclasses.replace(ch.comp, tile=tile))
+
+
 def _attention_case(mesh4):
     b, h, hkv, s_loc, d = 1, 2, 1, 16, 8
     q = jax.random.normal(KEY, (b, h, R * s_loc, d))
     kv = jax.random.normal(jax.random.PRNGKey(3), (b, hkv, R * s_loc, d))
     spec = P(None, None, "model", None)
 
-    def build(ch, comp=None):
-        fn = compile_overlap("ag_attention", ch, comp=comp, causal=True)
+    def build(ch, tile=None):
+        fn = compile_overlap("ag_attention", _with_tile(ch, tile), causal=True)
         return jax.jit(shard_map(fn, mesh4, in_specs=(spec,) * 3, out_specs=spec))
 
     return build, (q, kv, kv)
@@ -628,7 +602,7 @@ def test_tiled_attention_parity_xla(mesh4):
     base = BlockChannel(axis="model", num_channels=2)
     ref = np.asarray(build(base)(*args), np.float32)
     # an explicit (tm, ., tk) blocks (block_q, block_kv); tk=6 clamps to 4
-    tiled = np.asarray(build(base, comp=(8, 128, 6))(*args), np.float32)
+    tiled = np.asarray(build(base, tile=(8, 128, 6))(*args), np.float32)
     np.testing.assert_allclose(tiled, ref, atol=2e-5, rtol=2e-5)
 
     # tuner-resolved joint winner: ag_attention is an AG flow, so the f32
@@ -653,8 +627,8 @@ def test_tiled_moe_parity_xla(mesh4):
         out_specs=P("model", None),
     )
 
-    def build(ch, comp=None):
-        g = compile_overlap("ag_moe", ch, comp=comp, capacity_factor=8.0)
+    def build(ch, tile=None):
+        g = compile_overlap("ag_moe", _with_tile(ch, tile), capacity_factor=8.0)
 
         def f_(xs, wgu_, wdn_):
             ids, wts, _ = moe_router(xs, wr, num_experts=e, top_k=top_k)
@@ -664,7 +638,7 @@ def test_tiled_moe_parity_xla(mesh4):
 
     base = BlockChannel(axis="model")
     ref = np.asarray(build(base)(x, wgu, wdn), np.float32)
-    tiled = np.asarray(build(base, comp=(8, 8, 4))(x, wgu, wdn), np.float32)
+    tiled = np.asarray(build(base, tile=(8, 8, 4))(x, wgu, wdn), np.float32)
     np.testing.assert_allclose(tiled, ref, atol=2e-5, rtol=2e-5)
 
     # tuner-resolved joint winner (ag_rs flow: the tuner may pick a bf16
@@ -718,12 +692,15 @@ def test_apply_seq_ring_matches_apply_seq(mesh4, n_kv):
 
 
 def test_auto_keeps_unsupported_backend_loud():
-    # PR-2 contract: unsupported (kind, backend) raises at BUILD time — no
-    # resolution mode may defer it into the first trace
+    # unsupported (kind, backend) raises at BUILD time — a tuner-resolved
+    # channel is refused exactly like a hand-built one
+    ch = tune.resolve_channel(
+        "ag_attention", sig=SIGS["ag_attention"], world=R, space=tune.JOINT_SPACE
+    )
     with pytest.raises(NotImplementedError, match="copy engine"):
-        compile_overlap("ag_attention", "auto", backend="pallas")
+        compile_overlap("ag_attention", ch, backend="pallas")
     with pytest.raises(NotImplementedError, match="copy engine"):
-        compile_overlap("ag_attention", BlockChannel(axis="model"), comp="auto", backend="pallas")
+        compile_overlap("ag_attention", BlockChannel(axis="model"), backend="pallas")
 
 
 def test_space_is_part_of_entry_key(mesh4):
@@ -741,16 +718,42 @@ def test_base_channel_fields_inherited(mesh4):
     assert res.channel.comm.order == res.candidate.order
 
 
-# ---- channel="auto" end-to-end ----------------------------------------------
+# ---- channels resolved per call shape, lowered explicitly -------------------
 
 
-def _auto_and_explicit(kind, mesh4):
-    """(auto_fn, explicit_fn, baseline_fn, args): same specs, three lowerings."""
+def _resolving(kind, *, backend_="xla", overlapped=True, mesh=None, space=tune.DEFAULT_SPACE,
+               **kw):
+    """Per-shard callable that resolves its channel from its own operand
+    shapes at trace time (``tune.resolve_channel``; world from the manual
+    axis when no mesh is given) and lowers it through ``compile_overlap``."""
+
+    def fn(*args, **call_kw):
+        ch = tune.resolve_channel(
+            kind,
+            shapes=[jnp.shape(a) for a in args],
+            mesh=mesh,
+            world=None if mesh is not None else int(backend.axis_size("model")),
+            space=space,
+        )
+        g = compile_overlap(kind, ch, backend=backend_, overlapped=overlapped, **kw)
+        return g(*args, **call_kw)
+
+    return fn
+
+
+def _tuned_and_explicit(kind, mesh4):
+    """(tuned_fn, explicit_fn, baseline_fn, args, resolved): same specs,
+    three lowerings; ``tuned_fn`` resolves inside shard_map."""
     key = KEY
     resolved = tune.resolve_channel(kind, sig=SIGS[kind], mesh=mesh4)
 
     def sm(fn, in_specs, out_specs):
         return jax.jit(shard_map(fn, mesh4, in_specs=in_specs, out_specs=out_specs))
+
+    def lower(ch, ov, **kw):
+        if ch is None:
+            return _resolving(kind, overlapped=ov, mesh=mesh4, **kw)
+        return compile_overlap(kind, ch, overlapped=ov, **kw)
 
     if kind == "ag_matmul":
         _, m_loc, k, n = SIGS[kind]
@@ -761,7 +764,7 @@ def _auto_and_explicit(kind, mesh4):
         specs = ((P("model", None), P(None, None)), P(None, None))
 
         def build(ch, ov=True):
-            return sm(compile_overlap(kind, ch, overlapped=ov), *specs)
+            return sm(lower(ch, ov), *specs)
     elif kind == "matmul_rs":
         _, m, k_loc, n = SIGS[kind]
         args = (
@@ -771,7 +774,7 @@ def _auto_and_explicit(kind, mesh4):
         specs = ((P(None, "model"), P("model", None)), P("model", None))
 
         def build(ch, ov=True):
-            return sm(compile_overlap(kind, ch, overlapped=ov), *specs)
+            return sm(lower(ch, ov), *specs)
     elif kind == "ag_attention":
         b, h, hkv, s_loc, d = SIGS[kind]
         q = jax.random.normal(key, (b, h, R * s_loc, d))
@@ -780,7 +783,7 @@ def _auto_and_explicit(kind, mesh4):
         specs = ((P(None, None, "model"),) * 3, P(None, None, "model"))
 
         def build(ch, ov=True):
-            return sm(compile_overlap(kind, ch, overlapped=ov, causal=True), *specs)
+            return sm(lower(ch, ov, causal=True), *specs)
     else:  # ag_moe
         m_loc, dm, top_k, e_loc, f = SIGS[kind]
         e = e_loc * R
@@ -796,7 +799,7 @@ def _auto_and_explicit(kind, mesh4):
         )
 
         def build(ch, ov=True):
-            g = compile_overlap(kind, ch, overlapped=ov, capacity_factor=8.0)
+            g = lower(ch, ov, capacity_factor=8.0)
 
             def f_(xs, wgu, wdn):
                 ids, wts, _ = moe_router(xs, wr, num_experts=e, top_k=top_k)
@@ -804,15 +807,15 @@ def _auto_and_explicit(kind, mesh4):
 
             return sm(f_, *specs)
 
-    return build("auto"), build(resolved), build(resolved, False), args, resolved
+    return build(None), build(resolved), build(resolved, False), args, resolved
 
 
 @pytest.mark.parametrize("kind", tune.TUNABLE_KINDS)
 def test_channel_auto_parity(kind, mesh4):
-    auto_fn, explicit_fn, baseline_fn, args, resolved = _auto_and_explicit(kind, mesh4)
-    got = np.asarray(auto_fn(*args), np.float32)
+    tuned_fn, explicit_fn, baseline_fn, args, resolved = _tuned_and_explicit(kind, mesh4)
+    got = np.asarray(tuned_fn(*args), np.float32)
     want = np.asarray(explicit_fn(*args), np.float32)
-    # auto resolves to exactly the explicit channel's lowering: bit-identical
+    # per-call resolution lands on exactly the explicit channel: bit-identical
     np.testing.assert_array_equal(got, want)
     # ... and correct vs the non-overlapping baseline, at the tolerance of
     # the flow dtype the tuner picked (bf16 partials are genuinely lossy)
@@ -825,20 +828,20 @@ def test_channel_auto_parity(kind, mesh4):
 
 
 def test_comp_auto_parity_xla(mesh4):
-    # comp="auto" (joint search) must match the default-tile lowering; the
+    # a joint-space resolution must match the default-tile lowering; the
     # shape is big enough that the winner's tile genuinely differs
     m_loc, k, n = 256, 512, 256
     x = jax.random.normal(KEY, (R * m_loc, k))
     w = jax.random.normal(jax.random.PRNGKey(1), (k, n))
     specs = dict(in_specs=(P("model", None), P(None, None)), out_specs=P(None, None))
 
-    joint = jax.jit(shard_map(compile_overlap("ag_matmul", "auto", comp="auto"), mesh4, **specs))
+    joint = jax.jit(shard_map(_resolving("ag_matmul", space=tune.JOINT_SPACE), mesh4, **specs))
     got = np.asarray(joint(x, w), np.float32)  # resolves the joint winner
 
     res = tune.autotune("ag_matmul", signature=(1, m_loc, k, n), world=R, space=tune.JOINT_SPACE)
     assert res.cache_hit and res.candidate.comp_tile != DEFAULT_TILE  # joint hit
 
-    default = res.channel.with_(comp=dataclasses.replace(res.channel.comp, tile=DEFAULT_TILE))
+    default = _with_tile(res.channel, DEFAULT_TILE)
     ref_fn = jax.jit(shard_map(compile_overlap("ag_matmul", default), mesh4, **specs))
     want = np.asarray(ref_fn(x, w), np.float32)
     if res.candidate.accum_dtype == "float32":
@@ -856,9 +859,9 @@ def test_comp_explicit_tile_parity_pallas(mesh4):
     w = jax.random.normal(jax.random.PRNGKey(2), (k, n))
     specs = dict(in_specs=(P("model", None), P(None, None)), out_specs=P(None, None))
 
-    def pallas_fn(comp):
-        ch = BlockChannel(axis="model")
-        fn = compile_overlap("ag_matmul", ch, comp=comp, backend="pallas", world_size=R)
+    def pallas_fn(tile):
+        ch = _with_tile(BlockChannel(axis="model"), tile)
+        fn = compile_overlap("ag_matmul", ch, backend="pallas", world_size=R)
         return jax.jit(shard_map(fn, mesh4, **specs))
 
     tiled = np.asarray(pallas_fn((8, 16, 16))(x, w), np.float32)
@@ -869,9 +872,9 @@ def test_comp_explicit_tile_parity_pallas(mesh4):
     wr = jax.random.normal(jax.random.PRNGKey(3), (R * 8, 32))
     rs_specs = dict(in_specs=(P(None, "model"), P("model", None)), out_specs=P("model", None))
 
-    def rs_fn(comp):
-        ch = BlockChannel(axis="model")
-        fn = compile_overlap("matmul_rs", ch, comp=comp, backend="pallas", world_size=R)
+    def rs_fn(tile):
+        ch = _with_tile(BlockChannel(axis="model"), tile)
+        fn = compile_overlap("matmul_rs", ch, backend="pallas", world_size=R)
         return jax.jit(shard_map(fn, mesh4, **rs_specs))
 
     tiled_rs = np.asarray(rs_fn((8, 16, 4))(xr, wr), np.float32)
@@ -885,8 +888,8 @@ def test_comp_auto_parity_pallas(mesh4):
     m_loc, k, n = 16, 32, 32
     x = jax.random.normal(KEY, (R * m_loc, k))
     w = jax.random.normal(jax.random.PRNGKey(2), (k, n))
-    fn = compile_overlap(
-        "ag_matmul", "auto", comp="auto", backend="pallas", mesh=mesh4, world_size=R
+    fn = _resolving(
+        "ag_matmul", backend_="pallas", mesh=mesh4, space=tune.JOINT_SPACE, world_size=R
     )
     specs = dict(in_specs=(P("model", None), P(None, None)), out_specs=P(None, None))
     sm = jax.jit(shard_map(fn, mesh4, **specs))
@@ -894,56 +897,55 @@ def test_comp_auto_parity_pallas(mesh4):
 
 
 def test_comp_rejects_bad_values():
-    with pytest.raises(ValueError, match="comp must be"):
-        compile_overlap("ag_matmul", BlockChannel(axis="model"), comp="fastest")
-    with pytest.raises(ValueError, match="comp must be"):
-        compile_overlap("ag_matmul", "auto", comp=(128, 128))
-    # explicit CompSpec replaces the whole compute half (tile AND flow dtype)
-    fn = compile_overlap("ag_matmul", BlockChannel(axis="model"), comp=CompSpec(tile=(64, 64, 64)))
+    ch = BlockChannel(axis="model")
+    # the compute half travels on the channel only: comp= names its replacement
+    with pytest.raises(TypeError, match=r"channel\.with_\(comp="):
+        compile_overlap("ag_matmul", ch, comp=(64, 64, 64))
+    with pytest.raises(ValueError, match="comp tile must be"):
+        CompSpec(tile=(128, 128))
+    # an explicit CompSpec replaces the whole compute half (tile AND flow dtype)
+    fn = compile_overlap("ag_matmul", ch.with_(comp=CompSpec(tile=(64, 64, 64))))
     assert fn.keywords["channel"].comp.tile == (64, 64, 64)
     assert fn.keywords["channel"].comp.accum_dtype == "float32"
-    # a bare tuple pins the TILE only — the channel's flow dtype survives
+    # replacing the tile alone keeps the channel's flow dtype
     bf16 = BlockChannel(axis="model", comp=CompSpec(accum_dtype="bfloat16"))
-    fn2 = compile_overlap("matmul_rs", bf16, comp=(64, 64, 64))
+    fn2 = compile_overlap("matmul_rs", _with_tile(bf16, (64, 64, 64)))
     assert fn2.keywords["channel"].comp.tile == (64, 64, 64)
     assert fn2.keywords["channel"].comp.accum_dtype == "bfloat16"
 
 
 def test_auto_channel_with_pinned_comp_honors_tile(mesh4):
-    # channel="auto" + explicit comp: the comm half is searched, the tile is
+    # a space with one pinned tile: the comm half is searched, the tile is
     # pinned — the resolved lowering must actually carry the (clamped)
     # explicit tile, not the backend-chosen sentinel
     m_loc, k, n = 16, 32, 32
     x = jax.random.normal(KEY, (R * m_loc, k))
     w = jax.random.normal(jax.random.PRNGKey(1), (k, n))
     specs = dict(in_specs=(P("model", None), P(None, None)), out_specs=P(None, None))
-    fn = jax.jit(shard_map(compile_overlap("ag_matmul", "auto", comp=(8, 16, 16)), mesh4, **specs))
+    pinned = tune.Space(comp_tiles=((8, 16, 16),))  # tile pinned, rest swept
+    fn = jax.jit(shard_map(_resolving("ag_matmul", space=pinned), mesh4, **specs))
     np.testing.assert_allclose(np.asarray(fn(x, w)), np.asarray(x @ w), atol=2e-4, rtol=2e-3)
-    res = tune.autotune(
-        "ag_matmul",
-        signature=(1, m_loc, k, n),
-        world=R,
-        space=tune.Space(comp_tiles=((8, 16, 16),)),  # tile pinned, rest swept
-    )
+    res = tune.autotune("ag_matmul", signature=(1, m_loc, k, n), world=R, space=pinned)
     assert res.cache_hit  # the traced call resolved exactly this pinned space
     assert res.candidate.comp_tile == (8, 16, 16)
     assert res.channel.comp.tile == (8, 16, 16)
 
 
 def test_auto_resolves_without_mesh_inside_shard_map(mesh4):
-    # no mesh kwarg: world comes from axis_size inside the manual region and
-    # the fingerprint narrows to the collective axis
+    # no mesh: world comes from axis_size inside the manual region and the
+    # fingerprint narrows to the collective axis
     _, m_loc, k, n = SIGS["ag_matmul"]
     x = jax.random.normal(KEY, (R * m_loc, k))
     w = jax.random.normal(jax.random.PRNGKey(1), (k, n))
     sm = shard_map(
-        compile_overlap("ag_matmul", "auto"),
+        _resolving("ag_matmul"),
         mesh4,
         in_specs=(P("model", None), P(None, None)),
         out_specs=P(None, None),
     )
     fn = jax.jit(sm)
     np.testing.assert_allclose(np.asarray(fn(x, w)), np.asarray(x @ w), atol=2e-4, rtol=2e-3)
+    assert tune.autotune("ag_matmul", signature=SIGS["ag_matmul"], world=R).cache_hit
 
 
 def test_parallel_context_tune_resolves(mesh4):
@@ -961,12 +963,192 @@ def test_parallel_context_tune_resolves(mesh4):
     assert len(os.listdir(tune_cache.cache_dir())) == 1
 
 
+def _pc_case(op, mesh4):
+    """(pc_call(pc, *args), explicit(*args), args, in_specs, out_specs) for
+    one ParallelContext op: ``explicit`` lowers the channel(s) the matching
+    ``tune.resolve_*`` returns for the call's per-shard shapes."""
+    space = tune.JOINT_SPACE
+    r = jax.random.PRNGKey
+    if op in ("ag_matmul", "matmul_rs"):
+        _, a, b, c = SIGS[op]
+        if op == "ag_matmul":
+            args = (jax.random.normal(KEY, (R * a, b)), jax.random.normal(r(1), (b, c)))
+            specs = (P("model", None), P(None, None)), P(None, None)
+            shard = [(a, b), (b, c)]
+        else:
+            args = (jax.random.normal(KEY, (a, R * b)), jax.random.normal(r(2), (R * b, c)))
+            specs = (P(None, "model"), P("model", None)), P("model", None)
+            shard = [(a, b), (b, c)]
+        ch = tune.resolve_channel(op, shapes=shard, mesh=mesh4, space=space)
+        return (lambda pc, *xs: getattr(pc, op)(*xs),
+                compile_overlap(op, ch), args) + specs
+    if op == "ag_attention":
+        b, h, hkv, s_loc, d = SIGS[op]
+        q = jax.random.normal(KEY, (b, h, R * s_loc, d))
+        kv = jax.random.normal(r(3), (b, hkv, R * s_loc, d))
+        shard = [(b, h, s_loc, d), (b, hkv, s_loc, d), (b, hkv, s_loc, d)]
+        ch = tune.resolve_channel(op, shapes=shard, mesh=mesh4, space=space)
+        spec = P(None, None, "model")
+        return (lambda pc, *xs: pc.ring_attention(*xs, causal=True),
+                compile_overlap(op, ch, causal=True), (q, kv, kv), (spec,) * 3, spec)
+    if op in ("ag_moe", "a2a"):
+        m_loc, dm, top_k, e_loc, f = SIGS["ag_moe"]
+        e = e_loc * R
+        args = (jax.random.normal(KEY, (R * m_loc, dm)) * 0.5,
+                jax.random.normal(r(5), (e, dm, 2 * f)) * 0.1,
+                jax.random.normal(r(6), (e, f, dm)) * 0.1)
+        wr = jax.random.normal(r(4), (dm, e))
+        shard = [(m_loc, dm), (m_loc, top_k), (m_loc, top_k), (e_loc, dm, 2 * f),
+                 (e_loc, f, dm)]
+        if op == "ag_moe":
+            ch = tune.resolve_channel(op, shapes=shard, mesh=mesh4, space=space)
+            g = compile_overlap(op, ch, capacity_factor=8.0)
+
+            def call(pc, xs, ids, wts, wgu, wdn):
+                return pc.ag_moe(xs, ids, wts, wgu, wdn, capacity_factor=8.0)
+        else:
+            fused, ch_d, ch_c = tune.resolve_a2a(shapes=shard, mesh=mesh4, space=space,
+                                                 capacity_factor=8.0)
+            g = compile_overlap([("a2a_dispatch", ch_d), ("combine_rs", ch_c)],
+                                overlapped=fused, capacity_factor=8.0)
+
+            def call(pc, xs, ids, wts, wgu, wdn):
+                return pc.a2a_moe(xs, ids, wts, wgu, wdn, capacity_factor=8.0)
+
+        def routed(fn):
+            def f_(xs, wgu, wdn):
+                ids, wts, _ = moe_router(xs, wr, num_experts=e, top_k=top_k)
+                return fn(xs, ids, wts, wgu, wdn)
+            return f_
+
+        specs = (P("model", None), P("model", None, None), P("model", None, None))
+        return (lambda pc, *xs: routed(lambda *a: call(pc, *a))(*xs), routed(g), args,
+                specs, P("model", None))
+    # the RS -> AG seam
+    lead, m_glob, k_loc, n_mid, n2_loc = SEAM_SIG
+    args = (jax.random.normal(KEY, (m_glob, R * k_loc)),
+            jax.random.normal(r(1), (R * k_loc, n_mid)),
+            jax.random.normal(r(2), (n_mid, R * n2_loc)))
+    shard = [(m_glob, k_loc), (k_loc, n_mid), (n_mid, n2_loc)]
+    fused, ch_rs, ch_ag = tune.resolve_seq(shapes=shard, mesh=mesh4, space=space)
+    assert fused
+    g = compile_overlap([("matmul_rs", ch_rs), ("ag_matmul", ch_ag)])
+    specs = (P(None, "model"), P("model", None), P(None, "model"))
+    return (lambda pc, *xs: pc.matmul_rs_ag(*xs), g, args, specs,
+            (P("model", None), P(None, "model")))
+
+
+@pytest.mark.parametrize("op", ["ag_matmul", "matmul_rs", "ag_attention", "ag_moe",
+                                "seam", "a2a"])
+def test_parallel_context_tuned_matches_explicit_channel(op, mesh4):
+    """``ParallelContext(tune=True)`` lowers exactly the channel the
+    matching ``tune.resolve_*`` returns for the call's shapes."""
+    from repro.parallel.context import ParallelContext
+
+    call, explicit, args, in_specs, out_specs = _pc_case(op, mesh4)
+    pc = ParallelContext(mesh=mesh4, axis="model", dp_axes=(), tune=True,
+                         ep_axis="model" if op == "a2a" else None)
+    tuned = jax.jit(pc.smap(lambda *xs: call(pc, *xs), in_specs, out_specs))
+    want = jax.jit(shard_map(explicit, mesh4, in_specs=in_specs, out_specs=out_specs))
+    for got, ref in zip(jax.tree_util.tree_leaves(tuned(*args)),
+                        jax.tree_util.tree_leaves(want(*args))):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+@pytest.mark.parametrize("removed", [
+    {"channel": "auto"},
+    {"comp": "auto"},
+    {"quant": "auto"},
+    {"axis": "model"},
+    {"mesh": None},
+    {"tune_ranker": "model"},
+    {"tune_base": None},
+    {"tune_space": None},
+])
+def test_removed_compile_overlap_forms_name_replacement(removed):
+    """``compile_overlap`` lowers explicit channels only; each removed
+    tuning form fails at build time and says what replaces it."""
+    kw = dict(removed)
+    channel = kw.pop("channel", BlockChannel(axis="model"))
+    want = {"channel": "ParallelContext", "comp": r"channel\.with_\(comp=",
+            "quant": r"channel\.with_\(quant=", "axis": r"BlockChannel\(axis="}
+    match = want.get(next(iter(removed)), r"ParallelContext\(tune=True\).*resolve_seq")
+    for kind in ("ag_matmul", ["matmul_rs", "ag_matmul"], ["a2a_dispatch", "combine_rs"]):
+        with pytest.raises((TypeError, ValueError), match=match):
+            compile_overlap(kind, channel, **kw)
+
+
+# ---- the autotuner's contract per kind, on each kind's own shapes -----------
+
+# one small per-shard signature per kind (see repro.tune.signature)
+SMOKE_SIGS = {
+    "ag_matmul": (1, 32, 32, 32),  # (lead, m_loc, k, n_loc)
+    "matmul_rs": (1, 64, 16, 32),  # (lead, m_glob, k_loc, n)
+    "ag_attention": (1, 2, 1, 32, 16),  # (b, h, hkv, s_loc, d)
+    "ag_moe": (32, 16, 2, 2, 16),  # (m_loc, d_model, top_k, e_loc, f)
+}
+# joint-space shapes: large enough that each kind's tile lattice survives
+# divisor/alignment pruning
+JOINT_SIGS = {
+    "ag_matmul": (1, 256, 512, 256),
+    "matmul_rs": (1, 1024, 128, 512),
+    "ag_attention": (1, 2, 1, 64, 32),
+    "ag_moe": (32, 16, 2, 2, 16),
+}
+
+
+def _assert_winner_parity(kind, res, mesh4):
+    """The tuned channel, lowered explicitly, matches the default channel."""
+    build, args = tune_measure.build_case(kind, mesh4, "model", res.signature)
+    got = np.asarray(build(res.channel)(*args), np.float32)
+    ref = np.asarray(build(BlockChannel(axis="model"))(*args), np.float32)
+    tol = 1e-3 if res.candidate.accum_dtype == "float32" else 1e-1
+    assert np.max(np.abs(got - ref)) < tol * max(1.0, float(np.max(np.abs(ref))))
+
+
+@pytest.mark.parametrize("kind", tune.TUNABLE_KINDS)
+def test_tuned_channel_cache_round_trip_and_parity(kind, mesh4):
+    kw = dict(signature=SMOKE_SIGS[kind], mesh=mesh4, ranker="model")
+    first = tune.autotune(kind, **kw)
+    memo = tune.autotune(kind, **kw)
+    tune_cache.clear_memo()  # force the disk read
+    disk = tune.autotune(kind, **kw)
+    assert not first.cache_hit
+    assert memo.cache_hit and disk.cache_hit
+    assert memo.candidate == first.candidate == disk.candidate
+    _assert_winner_parity(kind, first, mesh4)
+
+
+@pytest.mark.parametrize("kind", tune.TUNABLE_KINDS)
+def test_joint_winner_parity_every_kind(kind, mesh4):
+    res = tune.autotune(kind, signature=JOINT_SIGS[kind], mesh=mesh4, ranker="model",
+                        space=tune.JOINT_SPACE)
+    assert res.considered > 18  # every kind has a compute-tile axis
+    _assert_winner_parity(kind, res, mesh4)
+
+
+@pytest.mark.parametrize("kind", tune.TUNABLE_KINDS)
+def test_measured_sweep_prunes_every_kind(kind):
+    sig = JOINT_SIGS[kind]
+    cands = tune.enumerate_candidates(
+        kind, extent=tune.chunk_extent(kind, sig), space=tune.JOINT_SPACE, sig=sig, world=R
+    )
+    timer = _oracle(kind, sig, R)
+    sw = tune_sweep.measured_sweep(kind, sig, R, cands, timer)
+    ex = tune_sweep.measured_sweep(
+        kind, sig, R, cands, timer, config=tune_sweep.SweepConfig(enabled=False)
+    )
+    assert sw.winner == ex.winner
+    assert 2 * sw.stats["screened"] <= len(cands)  # timed <= 50% of the space
+    assert 2 * sw.stats["pruned"] >= len(cands)  # pruned >= 50% unmeasured
+
+
 def test_bad_inputs():
     with pytest.raises(ValueError, match="not tunable"):
         tune.enumerate_candidates("nope")
     with pytest.raises(ValueError, match="mesh or an explicit world"):
         tune.autotune("ag_matmul", signature=(1, 8, 8, 8))
-    with pytest.raises(ValueError, match="BlockChannel or 'auto'"):
+    with pytest.raises(ValueError, match="explicit BlockChannel"):
         compile_overlap("ag_matmul", "fastest")
     with pytest.raises(ValueError, match="unknown ranker"):
         tune.autotune("ag_matmul", signature=(1, 8, 8, 8), world=4, ranker="vibes")
@@ -1005,3 +1187,32 @@ def test_resolve_seq_verdicts_fused_with_shared_channels():
     assert fused
     assert ch_rs.num_channels == ch_ag.num_channels
     assert ch_rs.comm.order == ch_ag.comm.order
+
+
+# dense FFN seam signatures (lead, m_glob, k_loc, n_mid, n2_loc) at world 4:
+# one block's down-proj GEMM+RS feeding the next block's AG+GEMM
+DENSE_SEAMS = {
+    "small": (1, 64, 32, 64, 32),
+    "mlp-1k": (1, 1024, 256, 1024, 512),
+    "mlp-4k": (1, 4096, 1024, 4096, 2048),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DENSE_SEAMS))
+def test_fused_seam_beats_unfused_pair_modeled(name):
+    """On every dense FFN seam shape the best fused candidate beats the best
+    unfused pair on the modeled scale, and resolve_seq verdicts fused with
+    one shared channel."""
+    from repro.tune import cost as tune_cost
+
+    sig = DENSE_SEAMS[name]
+    cands = tune.enumerate_seq_candidates(sig=sig, world=R)
+    assert cands
+
+    def best(fused):
+        return min(tune_cost.predict_seq_cost(sig, R, c, fused=fused) for c in cands)
+
+    assert best(True) < best(False)
+    fused, ch_rs, ch_ag = tune.resolve_seq(sig=sig, world=R)
+    assert fused and ch_rs == ch_ag
+
